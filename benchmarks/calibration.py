@@ -186,10 +186,11 @@ def main(out=print, quick: bool = False, check: bool = False,
     from repro.calibrate import bench, fit
     from repro.calibrate.profile import CalibrationProfile
     from repro.configs import DeviceInfo, MeshConfig
+    from repro.launch.mesh import make_mesh_from_config
 
     n_dev = len(jax.devices())
     mesh_cfg = MeshConfig((n_dev, 1), ("data", "model"))
-    mesh = jax.make_mesh(mesh_cfg.shape, mesh_cfg.axes)
+    mesh = make_mesh_from_config(mesh_cfg)
 
     # --- calibrate this backend ------------------------------------------
     repeats = 2 if quick else 3

@@ -25,6 +25,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 from repro.configs import (DENSE, MeshConfig, ModelConfig, OSDPConfig,  # noqa: E402
                            RunConfig, get_shape)
 from repro.core.plan import make_plan  # noqa: E402
+from repro.launch.mesh import make_mesh_from_config  # noqa: E402
 from repro.data.synthetic import Dataset  # noqa: E402
 from repro.models.registry import build_model  # noqa: E402
 from repro.optim import AdamWConfig  # noqa: E402
@@ -56,7 +57,7 @@ def run_plan(label: str, force_mode, steps: int, seq: int, batch: int,
                       operator_splitting=force_mode is None)
     run = RunConfig(model=MODEL, shape=shape, mesh=mesh_cfg, osdp=osdp)
     plan = make_plan(run)
-    mesh = jax.make_mesh(mesh_cfg.shape, mesh_cfg.axes)
+    mesh = make_mesh_from_config(mesh_cfg)
     built = build_model(run, plan, mesh)
     ds = Dataset(MODEL, shape, seed=0)
     with jax.set_mesh(mesh):

@@ -7,10 +7,10 @@ from __future__ import annotations
 from repro.configs.base import (  # noqa: F401
     AUDIO, DENSE, HYBRID, MOE, SSM, VLM,
     DECODE_32K, LONG_500K, PREFILL_32K, TRAIN_4K, SHAPES,
-    SINGLE_POD_MESH, MULTI_POD_MESH, DEVICE_PRESETS,
+    SINGLE_POD_MESH, MULTI_POD_MESH, DEVICE_KIND_PRESETS, DEVICE_PRESETS,
     ILP_BACKENDS, PRESET_CATALOG, PRESET_OVERLAP, SOLVERS,
     DeviceInfo, DevicePreset, MeshConfig, ModelConfig, OSDPConfig,
-    RunConfig, ShapeConfig, reduced,
+    RunConfig, ShapeConfig, preset_for_device, reduced,
 )
 
 from repro.configs.arctic_480b import CONFIG as _arctic

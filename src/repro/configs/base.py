@@ -343,6 +343,29 @@ PRESET_CATALOG = {
 
 DEVICE_PRESETS = tuple(sorted(PRESET_CATALOG))
 
+# `device_kind` as JAX reports it -> the catalog entry that prices it.
+DEVICE_KIND_PRESETS = {
+    "TPU v5 lite": "tpu-v5e",
+    "TPU v4": "tpu-v4",
+}
+
+
+def preset_for_device(device) -> str:
+    """Catalog name for an attached JAX device.  The CPU backend is a
+    rehearsal host, not a planning target: it plans for tpu-v5e, the
+    chip this repo runs on.  Any other device whose `device_kind` is
+    not in DEVICE_KIND_PRESETS raises — it is never priced as
+    another chip; pass an explicit preset (`--device`) instead."""
+    if device.platform == "cpu":
+        return "tpu-v5e"
+    try:
+        return DEVICE_KIND_PRESETS[device.device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no device preset for {device.platform} device kind "
+            f"{device.device_kind!r}; known kinds: "
+            f"{sorted(DEVICE_KIND_PRESETS)}") from None
+
 # legacy view kept for callers that index the overlap table directly;
 # derived from the catalog so the constants live in exactly one place
 PRESET_OVERLAP = {name: p.achievable_overlap

@@ -393,6 +393,10 @@ def _solve_bnb(groups: List[_Group], need: float, node_budget: int,
     Budget exhaustion returns the best incumbent plus the smallest
     outstanding node priority — a proven lower bound (anytime mode)."""
     t0 = _time.perf_counter()
+    # coverage sums are taken in several orders (capacity tables,
+    # greedy, paths) that differ by a few ulps; without this slack a
+    # need equal to the full capacity reads as uncoverable
+    need -= 1e-12 * need
     tables = _DualTables(groups)
     levels = tables.levels
     L = len(levels)

@@ -8,8 +8,9 @@ and an fp32 VMEM accumulator — at any instant exactly one (bk, bn)
 weight tile is resident on-chip, which *is* the paper's slice-and-sum
 schedule with slice_granularity = K/bk (DESIGN.md §3).
 
-Block shapes default to MXU-aligned 512x512x512 and are clamped to the
-problem size.
+Block shapes default to MXU-aligned 512x512x512, are clamped to the
+problem size, and shrink to the largest tile-aligned divisor of a
+dimension they do not divide (d_ff 2816 takes 256-wide blocks).
 """
 from __future__ import annotations
 
@@ -34,6 +35,18 @@ def _kernel(x_ref, w_ref, o_ref, acc_ref):
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
+def _fit_block(dim: int, want: int, align: int) -> int:
+    """Largest block <= `want` that divides `dim`, a multiple of `align`
+    unless it is `want` itself; the whole dimension when none is."""
+    b = min(want, dim)
+    if dim % b == 0:
+        return b
+    for cand in range(b - b % align, 0, -align):
+        if dim % cand == 0:
+            return cand
+    return dim
+
+
 @functools.partial(jax.jit,
                    static_argnames=("bm", "bn", "bk", "interpret"))
 def split_matmul(x: jax.Array, w: jax.Array, *, bm: int = 512,
@@ -43,9 +56,9 @@ def split_matmul(x: jax.Array, w: jax.Array, *, bm: int = 512,
     m, k = x.shape
     k2, n = w.shape
     assert k == k2, (x.shape, w.shape)
-    bm, bn, bk = min(bm, m), min(bn, n), min(bk, k)
-    assert m % bm == 0 and n % bn == 0 and k % bk == 0, (
-        f"dims {(m, k, n)} must divide blocks {(bm, bk, bn)}")
+    # TPU tiles: rows in multiples of 16 (bf16 packing), lanes of 128
+    bm, bn, bk = _fit_block(m, bm, 16), _fit_block(n, bn, 128), \
+        _fit_block(k, bk, 128)
     grid = (m // bm, n // bn, k // bk)
     return pl.pallas_call(
         _kernel,

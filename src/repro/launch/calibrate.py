@@ -83,6 +83,7 @@ def main(argv=None) -> int:
     import jax
     from repro.calibrate import bench, fit
     from repro.calibrate.profile import CalibrationProfile
+    from repro.launch.mesh import make_mesh
 
     t0 = time.perf_counter()
 
@@ -98,7 +99,7 @@ def main(argv=None) -> int:
     n_dev = len(jax.devices())
     links = ()
     if n_dev >= 2:
-        mesh = jax.make_mesh((n_dev,), ("data",))
+        mesh = make_mesh((n_dev,), ("data",))
         sweeps = bench.collective_sweep(mesh, args.bw_mib,
                                         repeats=args.repeats)
         links = fit.fit_link_calibrations(sweeps)

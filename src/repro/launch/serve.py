@@ -34,8 +34,9 @@ import jax
 import numpy as np
 
 from repro.configs import (DeviceInfo, MeshConfig, OSDPConfig, RunConfig,
-                           get_arch, get_shape, reduced)
+                           get_arch, get_shape, preset_for_device, reduced)
 from repro.core.api import search_serve
+from repro.launch.cache import enable_compilation_cache
 from repro.models.registry import build_model
 from repro.serving.engine import ContinuousEngine, Engine, Request
 
@@ -56,7 +57,8 @@ def main(argv=None) -> int:
                          "static batching")
     ap.add_argument("--device", default=None, metavar="PRESET",
                     help="DeviceInfo preset to plan for "
-                         "(tpu-v5e, tpu-v4, a100-80g, h100-sxm)")
+                         "(tpu-v5e, tpu-v4, a100-80g, h100-sxm; "
+                         "default: the attached device's)")
     ap.add_argument("--n-devices", type=int, default=1,
                     help="data extent the plan targets")
     ap.add_argument("--memory-limit-gib", type=float, default=16.0)
@@ -97,6 +99,7 @@ def main(argv=None) -> int:
                     help="per-request engine-step deadline "
                          "(0 = none); expired requests end TIMED_OUT")
     args = ap.parse_args(argv)
+    enable_compilation_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:
@@ -105,14 +108,15 @@ def main(argv=None) -> int:
         print(f"{cfg.name} is encoder-only; nothing to decode")
         return 1
 
+    device = DeviceInfo.preset(
+        args.device or preset_for_device(jax.devices()[0]))
     if args.fleet:
-        return _serve_fleet(cfg, args)
+        return _serve_fleet(cfg, args, device)
 
     rng = np.random.default_rng(args.seed)
     if args.no_plan:
         return _serve_static(cfg, args, rng, plan=None)
 
-    device = DeviceInfo.preset(args.device) if args.device else None
     plan = search_serve(
         cfg, prompt_len=args.prompt_len, decode_len=args.new_tokens,
         n_devices=args.n_devices,
@@ -194,7 +198,7 @@ def _parse_classes(spec: str):
     return RequestClassMix(tuple(classes))
 
 
-def _serve_fleet(cfg, args) -> int:
+def _serve_fleet(cfg, args, device: DeviceInfo) -> int:
     """Fleet path: search_fleet over the class mix, then (with
     --reduced) drive the plan with the deterministic traffic
     simulator — one reduced engine per replica group."""
@@ -203,7 +207,6 @@ def _serve_fleet(cfg, args) -> int:
     from repro.core.api import search_fleet
 
     mix = _parse_classes(args.classes or DEFAULT_CLASSES)
-    device = DeviceInfo.preset(args.device) if args.device else None
     plan = search_fleet(cfg, mix=mix, n_devices=args.n_devices,
                         memory_limit_gib=args.memory_limit_gib,
                         device=device)

@@ -6,8 +6,10 @@
 
 Runs the OSDP pipeline (describe -> search -> plan), builds the model
 with the planned shardings on the local mesh, and trains on the
-synthetic pipeline. On a real TPU slice the same RunConfig lowers
-against make_production_mesh() instead (see launch/dryrun.py).
+synthetic pipeline. The planner prices the attached device (its
+`device_kind`) unless --device names a preset. On a real TPU slice the
+same RunConfig lowers against make_production_mesh() instead (see
+launch/dryrun.py).
 """
 from __future__ import annotations
 
@@ -18,8 +20,10 @@ import sys
 import jax
 
 from repro.configs import (DeviceInfo, MeshConfig, OSDPConfig, RunConfig,
-                           get_arch, get_shape, reduced)
+                           get_arch, get_shape, preset_for_device, reduced)
 from repro.core.plan import make_plan
+from repro.launch.cache import enable_compilation_cache
+from repro.launch.mesh import make_mesh_from_config
 from repro.models.registry import build_model
 from repro.optim import AdamWConfig
 from repro.sharding.specs import OverlapConfig
@@ -40,11 +44,12 @@ def main(argv=None) -> int:
     ap.add_argument("--memory-gib", type=float, default=16.0)
     ap.add_argument("--device", default=None, metavar="PRESET",
                     help="DeviceInfo preset the planner prices against "
-                         "(tpu-v5e, tpu-v4, a100-80g, h100-sxm)")
+                         "(tpu-v5e, tpu-v4, a100-80g, h100-sxm; "
+                         "default: the attached device's)")
     ap.add_argument("--overlap", default=None, metavar="FACTOR",
                     help="comm/compute overlap: a factor in [0, 1] for "
                          "the planner's timeline model, or 'auto' for "
-                         "the --device preset's catalog value; also "
+                         "the device preset's catalog value; also "
                          "turns on the runtime prefetch + gradient-"
                          "bucketing transforms (default: off, serial "
                          "model, legacy program)")
@@ -79,29 +84,24 @@ def main(argv=None) -> int:
             shape, seq_len=args.seq or shape.seq_len,
             global_batch=args.batch or shape.global_batch)
 
+    enable_compilation_cache()
     n_dev = len(jax.devices())
     mesh_cfg = MeshConfig((n_dev, 1), ("data", "model"))
     osdp = OSDPConfig(enabled=not args.no_osdp,
                       memory_limit_bytes=args.memory_gib * 2**30,
                       force_mode=args.force_mode)
     run = RunConfig(model=model_cfg, shape=shape, mesh=mesh_cfg, osdp=osdp)
-    overlap_cfg = None
+    ov, overlap_cfg = None, None
     if args.overlap is not None:
         ov = args.overlap if args.overlap == "auto" else float(args.overlap)
-        if args.device:
-            device = DeviceInfo.preset(args.device, overlap=ov)
-        elif ov == "auto":
-            ap.error("--overlap auto needs a --device preset")
-        else:
-            device = dataclasses.replace(DeviceInfo(), overlap=ov)
         overlap_cfg = OverlapConfig(
             prefetch=args.overlap_prefetch,
             bucket_bytes=int(args.overlap_bucket_mib * 2**20))
-    else:
-        device = DeviceInfo.preset(args.device) if args.device else None
+    device = DeviceInfo.preset(
+        args.device or preset_for_device(jax.devices()[0]), overlap=ov)
     plan = make_plan(run, device)
     print(plan.summary())
-    mesh = jax.make_mesh(mesh_cfg.shape, mesh_cfg.axes) if n_dev > 1 else None
+    mesh = make_mesh_from_config(mesh_cfg) if n_dev > 1 else None
     built = build_model(run, plan, mesh, overlap=overlap_cfg)
     res = train(built, args.steps, seed=args.seed,
                 opt_cfg=AdamWConfig(lr=args.lr), warmup=args.warmup,

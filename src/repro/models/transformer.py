@@ -483,17 +483,24 @@ def _attn_with_kv(model: Model, lp, h, positions, win):
 
 
 def _kv_to_cache(kv, alen: int):
+    """The last min(S, alen) positions in ring-buffer order (position p
+    at slot p % alen), built by padding or rolling: the TPU compiler
+    aborts on the equivalent scatter inside the layer scan."""
     k, v = kv
     B, S = k.shape[:2]
     take = min(alen, S)
-    pos = jnp.arange(S - take, S, dtype=jnp.int32)
-    slot = pos % alen
-    kc = jnp.zeros((B, alen) + k.shape[2:], k.dtype).at[:, slot].set(
-        k[:, S - take:])
-    vc = jnp.zeros((B, alen) + v.shape[2:], v.dtype).at[:, slot].set(
-        v[:, S - take:])
-    pc = jnp.full((B, alen), -1, jnp.int32).at[:, slot].set(pos[None])
-    return {"k": kc, "v": vc, "pos": pc}
+    pos = jnp.broadcast_to(jnp.arange(S - take, S, dtype=jnp.int32),
+                           (B, take))
+
+    def place(a, fill):
+        a = a[:, S - take:]
+        if take < alen:         # slots 0..S-1, the rest empty
+            pad = [(0, 0)] * a.ndim
+            pad[1] = (0, alen - take)
+            return jnp.pad(a, pad, constant_values=fill)
+        return jnp.roll(a, (S - alen) % alen, axis=1)
+
+    return {"k": place(k, 0), "v": place(v, 0), "pos": place(pos, -1)}
 
 
 def _ssm_with_state(model: Model, lp, h):

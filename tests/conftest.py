@@ -20,28 +20,14 @@ from repro.configs import (MeshConfig, OSDPConfig, RunConfig, get_arch,
 
 # --- pinned skip/xfail inventory --------------------------------------------
 # Modules whose tests may skip, with the only sanctioned reasons:
-#   test_kernels.py      — Pallas needs jax with pltpu.CompilerParams
-#   test_distributed.py  — needs jax.set_mesh (jax >= 0.6)
-#   test_cost_model.py / test_search.py / test_model_properties.py /
-#   test_solver_oracle.py
-#                        — hypothesis not installed in the local env
-#                          (CI installs it; these never skip there)
 #   test_ilp.py          — pinned ONLY when scipy is absent: the
 #                          milp-backend cases skip; the bnb cases and
 #                          everything else in the module still run
-# test_overlap.py, test_perf_probe.py, test_calibrate.py and
-# test_roofline.py are deliberately NOT listed: the overlap
-# timeline/runtime tests, the probe subprocess tests, the calibration
-# fit/equivalence tests and the HLO-parser pins run everywhere
-# (single-device CPU suffices) and must never skip.
-EXPECTED_SKIP_MODULES = frozenset({
-    "test_kernels.py",
-    "test_distributed.py",
-    "test_cost_model.py",
-    "test_search.py",
-    "test_model_properties.py",
-    "test_solver_oracle.py",
-})
+# Every other module must never skip on the installed stack (jax 0.9
+# with libtpu, hypothesis, scipy): the Pallas kernel sweeps, the
+# forced-multi-device distributed tests, the hypothesis property
+# modules and the described-TPU compile tests all run.
+EXPECTED_SKIP_MODULES = frozenset()
 try:
     from repro.core.ilp import HAVE_SCIPY_MILP as _HAVE_MILP
 except Exception:   # pragma: no cover - core must import for any test run
@@ -50,9 +36,7 @@ if not _HAVE_MILP:
     EXPECTED_SKIP_MODULES = EXPECTED_SKIP_MODULES | {"test_ilp.py"}
 # Exact tests that may xfail (an XPASS of these also fails the run —
 # a silently-passing xfail means the pin is stale):
-EXPECTED_XFAILS = (
-    "test_arch_smoke.py::test_decode_matches_full_forward[hymba-1.5b]",
-)
+EXPECTED_XFAILS = ()
 
 _inventory_violations = []
 

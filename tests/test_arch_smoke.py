@@ -94,14 +94,7 @@ def test_prefill_decode(arch):
     assert np.isfinite(np.asarray(lg2, np.float32)).all(), arch
 
 
-@pytest.mark.parametrize("arch", [
-    "mamba2-2.7b",
-    pytest.param("hymba-1.5b", marks=pytest.mark.xfail(
-        reason="known pre-existing hymba decode numerics drift: the "
-               "attn+ssm mean block's stepwise decode disagrees with "
-               "the full forward beyond bf16 tolerance (see "
-               "CHANGES.md); not a regression", strict=False)),
-])
+@pytest.mark.parametrize("arch", ["mamba2-2.7b", "hymba-1.5b"])
 def test_decode_matches_full_forward(arch):
     """Sub-quadratic archs: stepwise decode == full forward (recurrence
     correctness), up to bf16 noise."""

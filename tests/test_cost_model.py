@@ -1,8 +1,6 @@
 """Cost model (§3.1) unit + property tests (hypothesis)."""
-import pytest
-
-pytest.importorskip("hypothesis")
 import hypothesis.strategies as st
+import pytest
 from hypothesis import given, settings
 
 from repro.configs import (DeviceInfo, MULTI_POD_MESH, SINGLE_POD_MESH,

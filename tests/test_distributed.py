@@ -12,17 +12,6 @@ import subprocess
 import sys
 import textwrap
 
-import jax
-import pytest
-
-# Known pre-existing environment failure, not a code regression: the
-# subprocess scripts drive jax.set_mesh, which the CPU-only jax 0.4.x
-# in this image does not have yet.
-pytestmark = pytest.mark.skipif(
-    not hasattr(jax, "set_mesh"),
-    reason="distributed semantics tests need jax.set_mesh (>=0.6); "
-           "the CPU-only jax in this environment predates it")
-
 
 def _run(code: str) -> subprocess.CompletedProcess:
     env = dict(os.environ)
@@ -41,6 +30,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import (OSDPConfig, RunConfig, MeshConfig, get_arch,
                            get_shape, reduced)
 from repro.core.plan import make_plan, data_sharding
+from repro.launch.mesh import make_mesh
 from repro.models.registry import build_model, input_shardings
 from repro.train.loop import make_train_step
 from repro.optim import AdamWConfig
@@ -61,7 +51,7 @@ def losses_for(force_mode, split, arch="qwen1.5-0.5b", steps=3):
                       default_slice_granularity=max(split, 1))
     run = RunConfig(model=cfg, shape=shape, mesh=mesh_cfg, osdp=osdp)
     plan = make_plan(run)
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     built = build_model(run, plan, mesh)
     with jax.set_mesh(mesh):
         step_fn, init_fn = make_train_step(built, AdamWConfig(lr=1e-3),
@@ -102,7 +92,6 @@ def test_train_step_lowers_with_collectives():
     parameters and reduce-scatters of gradients."""
     code = COMMON + textwrap.dedent("""
         import dataclasses
-        from repro.launch.mesh import make_mesh_from_config
         cfg = reduced(get_arch("qwen1.5-0.5b"))
         mesh_cfg = MeshConfig((2, 2), ("data", "model"))
         shape = dataclasses.replace(get_shape("train_4k"), seq_len=64,
@@ -111,7 +100,7 @@ def test_train_step_lowers_with_collectives():
                         osdp=OSDPConfig(force_mode="ZDP",
                                         operator_splitting=False))
         plan = make_plan(run)
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         built = build_model(run, plan, mesh)
         with jax.set_mesh(mesh):
             step_fn, init_fn = make_train_step(built, donate=False)
@@ -149,7 +138,7 @@ def test_dp_vs_zdp_collective_bytes():
                                             operator_splitting=False,
                                             checkpointing=False))
             plan = make_plan(run)
-            mesh = jax.make_mesh((4, 1), ("data", "model"))
+            mesh = make_mesh((4, 1), ("data", "model"))
             built = build_model(run, plan, mesh)
             with jax.set_mesh(mesh):
                 step_fn, init_fn = make_train_step(built, donate=False)

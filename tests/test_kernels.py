@@ -1,20 +1,10 @@
 """Per-kernel shape/dtype sweeps vs the pure-jnp oracles (interpret=True)."""
 import jax
-import jax.experimental.pallas.tpu as pltpu
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
-
-# Known pre-existing environment failure, not a code regression: the
-# kernels target the pltpu.CompilerParams API; on the CPU-only
-# jax 0.4.x in this image that attribute does not exist and every
-# pallas_call raises AttributeError before interpret=True can help.
-pytestmark = pytest.mark.skipif(
-    not hasattr(pltpu, "CompilerParams"),
-    reason="Pallas kernels need jax with pltpu.CompilerParams "
-           "(>=0.5); the CPU-only jax in this environment predates it")
 
 
 def _tol(dtype):

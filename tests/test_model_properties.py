@@ -1,13 +1,11 @@
 """Property-based model invariants (hypothesis)."""
 import dataclasses
 
-import pytest
-
-pytest.importorskip("hypothesis")
 import hypothesis.strategies as st
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings
 
 from repro.models.attention import attention_ref, flash_attention

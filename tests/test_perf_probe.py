@@ -58,7 +58,8 @@ def test_measure_level_bandwidth_and_overlap_sanity():
         import jax
         from repro.launch.perf_probe import (measure_level_bandwidth,
                                              overlap_sanity)
-        mesh = jax.make_mesh((1, 2, 2), ("pod", "data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1, 2, 2), ("pod", "data", "model"))
         m = measure_level_bandwidth(mesh, size_mib=0.25, repeats=2)
         assert set(m) == {"pod", "data", "model"}
         assert m["pod"]["achieved_bytes_per_s"] is None      # span 1

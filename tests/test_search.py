@@ -5,10 +5,8 @@ import itertools
 import math
 import random
 
-import pytest
-
-pytest.importorskip("hypothesis")
 import hypothesis.strategies as st
+import pytest
 from hypothesis import given, settings
 
 from repro.configs import (DeviceInfo, SINGLE_POD_MESH, OSDPConfig,

@@ -136,7 +136,8 @@ def test_zdp_plan_shards_over_data_axis():
                         osdp=OSDPConfig(force_mode="ZDP",
                                         operator_splitting=False))
         plan = make_plan(run)
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2), ("data", "model"))
         built = build_model(run, plan, mesh)
         sh = built.shardings["layers/ffn/w13"]
         assert "data" in str(sh.spec), sh.spec

@@ -22,11 +22,9 @@ solver is supposed to satisfy:
 import itertools
 import math
 
-import pytest
-
-pytest.importorskip("hypothesis")
 import hypothesis.strategies as st
-from hypothesis import given, settings
+import pytest
+from hypothesis import example, given, settings
 
 from repro.core.ilp import HAVE_SCIPY_MILP, solve_ilp
 from repro.core.search import (SliceItem, _solve_dfs, _solve_greedy,
@@ -76,8 +74,20 @@ def _close(a, b):
     return math.isclose(a, b, rel_tol=1e-9, abs_tol=1e-9)
 
 
+# need == the full capacity, whose float sum depends on the order the
+# solver adds the savings in
+_FULL_COVER = ([SliceItem("op0", 0, 1, {"ZDP": 1.0}, {"ZDP": 1.0}),
+                SliceItem("op1", 0, 1, {"ZDP": 1.0}, {"ZDP": 1.0}),
+                SliceItem("op2", 0, 1, {"ZDP": 1.0, "ZDP+R": 2.0},
+                          {"ZDP": 0.5, "ZDP+R": 1.0}),
+                SliceItem("op3", 0, 1, {"ZDP": 12.820390474699648},
+                          {"ZDP": 1.0})],
+               16.82039047469965)
+
+
 @settings(max_examples=80, deadline=None)
 @given(instances())
+@example(_FULL_COVER)
 def test_ilp_bnb_matches_brute_force(inst):
     items, need = inst
     ref = _brute(items, need)
