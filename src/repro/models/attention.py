@@ -158,13 +158,16 @@ def attn_forward(cfg: ModelConfig, geom: AttnGeom, pset: ParamSet,
                  lp: Dict[str, jax.Array], x: jax.Array,
                  positions: jax.Array, *, window: int = 0) -> jax.Array:
     """Training / prefill attention over a full sequence."""
-    q, k, v = _proj_qkv(cfg, geom, pset, lp, x)
-    q = rotate(cfg, q.reshape(*q.shape[:2], -1, geom.head_dim), positions
-               ).reshape(q.shape)
-    k = rotate(cfg, k, positions)
+    with jax.named_scope("attention/qkv"):
+        q, k, v = _proj_qkv(cfg, geom, pset, lp, x)
+        q = rotate(cfg, q.reshape(*q.shape[:2], -1, geom.head_dim),
+                   positions).reshape(q.shape)
+        k = rotate(cfg, k, positions)
     win = window or cfg.sliding_window
-    o = flash_attention(q, k, v, causal=cfg.causal, window=win)
-    return _out_proj(geom, pset, lp, o)
+    with jax.named_scope("attention/core"):
+        o = flash_attention(q, k, v, causal=cfg.causal, window=win)
+    with jax.named_scope("attention/out"):
+        return _out_proj(geom, pset, lp, o)
 
 
 def init_kv_cache(cfg: ModelConfig, geom: AttnGeom, batch: int,
@@ -190,16 +193,17 @@ def attn_decode(cfg: ModelConfig, geom: AttnGeom, pset: ParamSet,
     {k:(B,Sc,KV,hd), v:..., pos:(B,Sc)}."""
     B = x.shape[0]
     Sc = cache["k"].shape[1]
-    q, k, v = _proj_qkv(cfg, geom, pset, lp, x)
     t_vec = jnp.broadcast_to(jnp.asarray(t, jnp.int32), (B,))
     if cfg.rope == "mrope":
         pos_arg = positions3                       # (B,1,3)
     else:
         pos_arg = t_vec[:, None]                   # (B,1)
-    if cfg.rope != "none":
-        q = rotate(cfg, q.reshape(B, 1, -1, geom.head_dim), pos_arg
-                   ).reshape(q.shape)
-        k = rotate(cfg, k, pos_arg)
+    with jax.named_scope("attention/qkv"):
+        q, k, v = _proj_qkv(cfg, geom, pset, lp, x)
+        if cfg.rope != "none":
+            q = rotate(cfg, q.reshape(B, 1, -1, geom.head_dim), pos_arg
+                       ).reshape(q.shape)
+            k = rotate(cfg, k, pos_arg)
     # per-sequence ring-buffer slot: a scatter row-by-row (identical to
     # the old dynamic_update_slice when every t is equal)
     slot = jnp.where(Sc > 0, t_vec % Sc, 0).astype(jnp.int32)
@@ -209,16 +213,18 @@ def attn_decode(cfg: ModelConfig, geom: AttnGeom, pset: ParamSet,
     pos_cache = cache["pos"].at[bidx, slot].set(t_vec)
 
     # single-row softmax over the cache (scores are (B,KV,Gp,1,Sc) — small)
-    s = jnp.einsum("bqkgh,btkh->bkgqt", q, k_cache,
-                   preferred_element_type=jnp.float32)
-    s = s / math.sqrt(geom.head_dim)
-    valid = pos_cache >= 0
-    if window:
-        valid = valid & (t_vec[:, None] - pos_cache < window)
-    valid = valid & (pos_cache <= t_vec[:, None])
-    s = jnp.where(valid[:, None, None, None, :], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgqt,btkh->bqkgh", p, v_cache.astype(jnp.float32)
-                   ).astype(x.dtype)
-    out = _out_proj(geom, pset, lp, o)
+    with jax.named_scope("attention/core"):
+        s = jnp.einsum("bqkgh,btkh->bkgqt", q, k_cache,
+                       preferred_element_type=jnp.float32)
+        s = s / math.sqrt(geom.head_dim)
+        valid = pos_cache >= 0
+        if window:
+            valid = valid & (t_vec[:, None] - pos_cache < window)
+        valid = valid & (pos_cache <= t_vec[:, None])
+        s = jnp.where(valid[:, None, None, None, :], s, NEG_INF)
+        p = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("bkgqt,btkh->bqkgh", p, v_cache.astype(jnp.float32)
+                       ).astype(x.dtype)
+    with jax.named_scope("attention/out"):
+        out = _out_proj(geom, pset, lp, o)
     return out, {"k": k_cache, "v": v_cache, "pos": pos_cache}

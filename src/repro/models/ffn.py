@@ -12,6 +12,7 @@ from repro.core.operator_split import chunked_ffn
 from repro.sharding.specs import ParamSet, seg_matmul
 
 
+@jax.named_scope("ffn")
 def ffn_forward(cfg: ModelConfig, pset: ParamSet, lp: Dict[str, jax.Array],
                 x: jax.Array, prefix: str = "layers/ffn",
                 granularity: int = 1) -> jax.Array:

@@ -62,6 +62,7 @@ def _expert_constrain(x: jax.Array, mesh, axis: int = 0) -> jax.Array:
         x, NamedSharding(mesh, P(*parts)))
 
 
+@jax.named_scope("moe")
 def moe_forward(cfg: ModelConfig, pset: ParamSet, lp: Dict[str, jax.Array],
                 x: jax.Array, mesh=None) -> Tuple[jax.Array, jax.Array]:
     """x: (B,S,d) -> (y: (B,S,d), aux_loss scalar)."""

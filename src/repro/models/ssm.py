@@ -166,6 +166,7 @@ def _split_proj(cfg: ModelConfig, pset: ParamSet, lp: Dict[str, jax.Array],
     return z, xin, b, c, dt
 
 
+@jax.named_scope("ssm")
 def ssm_forward(cfg: ModelConfig, pset: ParamSet, lp: Dict[str, jax.Array],
                 x: jax.Array) -> jax.Array:
     """Training / prefill SSD block. x: (B,S,d) -> (B,S,d)."""
@@ -197,6 +198,7 @@ def init_ssm_cache(cfg: ModelConfig, batch: int) -> Dict[str, jax.Array]:
     }
 
 
+@jax.named_scope("ssm")
 def ssm_decode(cfg: ModelConfig, pset: ParamSet, lp: Dict[str, jax.Array],
                x: jax.Array, cache: Dict[str, jax.Array]
                ) -> Tuple[jax.Array, Dict[str, jax.Array]]:

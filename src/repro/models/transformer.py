@@ -188,6 +188,7 @@ class Model:
                       ) -> Dict[str, jax.Array]:
         return {k: v for k, v in params.items() if k.startswith("layers/")}
 
+    @jax.named_scope("norm")
     def _norm(self, lp, x, prefix):
         bias = lp.get(prefix + "_bias") if self.cfg.norm == "layernorm" \
             else None
@@ -205,6 +206,7 @@ class Model:
         return body
 
     # -- embedding ----------------------------------------------------------
+    @jax.named_scope("embed")
     def embed(self, params: Dict[str, jax.Array], batch: Dict[str, jax.Array]
               ) -> jax.Array:
         cfg = self.cfg
@@ -225,7 +227,8 @@ class Model:
     def logits(self, params: Dict[str, jax.Array], x: jax.Array) -> jax.Array:
         cfg = self.cfg
         fb = params.get("final_norm/bias")
-        x = norm(cfg, x, params["final_norm/scale"], fb)
+        with jax.named_scope("norm"):
+            x = norm(cfg, x, params["final_norm/scale"], fb)
         if cfg.tie_embeddings:
             logits = x @ params["embed/tok"].T
         else:
@@ -318,26 +321,27 @@ class Model:
         # (B, S, V) logits never fully materialize (beyond-paper;
         # matters for the 128k-200k vocab archs at seq 4k)
         chunk = 512
-        if (S % chunk == 0 and S > chunk
-                and S * cfg.padded_vocab >= 2**27):
-            nb = S // chunk
-            xb = jnp.moveaxis(
-                x.reshape(x.shape[0], nb, chunk, x.shape[-1]), 1, 0)
-            lb = jnp.moveaxis(labels.reshape(labels.shape[0], nb, chunk),
-                              1, 0)
+        with jax.named_scope("loss"):
+            if (S % chunk == 0 and S > chunk
+                    and S * cfg.padded_vocab >= 2**27):
+                nb = S // chunk
+                xb = jnp.moveaxis(
+                    x.reshape(x.shape[0], nb, chunk, x.shape[-1]), 1, 0)
+                lb = jnp.moveaxis(
+                    labels.reshape(labels.shape[0], nb, chunk), 1, 0)
 
-            def body(carry, blk):
-                s, n = carry
-                bs, bn = jax.checkpoint(self._ce_block)(params, *blk)
-                return (s + bs, n + bn), None
+                def body(carry, blk):
+                    s, n = carry
+                    bs, bn = jax.checkpoint(self._ce_block)(params, *blk)
+                    return (s + bs, n + bn), None
 
-            (nll_sum, n_valid), _ = jax.lax.scan(
-                body, (jnp.zeros(()), jnp.zeros(())), (xb, lb))
-        else:
-            nll_sum, n_valid = self._ce_block(params, x, labels)
-        denom = jnp.maximum(n_valid, 1.0)
-        ce = nll_sum / denom
-        loss = ce + 0.01 * aux / max(1, cfg.n_layers)
+                (nll_sum, n_valid), _ = jax.lax.scan(
+                    body, (jnp.zeros(()), jnp.zeros(())), (xb, lb))
+            else:
+                nll_sum, n_valid = self._ce_block(params, x, labels)
+            denom = jnp.maximum(n_valid, 1.0)
+            ce = nll_sum / denom
+            loss = ce + 0.01 * aux / max(1, cfg.n_layers)
         return loss, {"ce": ce, "aux": aux, "tokens": n_valid}
 
     # -- serving --------------------------------------------------------------
@@ -361,7 +365,8 @@ class Model:
         scalar position or a (B,) vector (continuous batching decodes
         every slot at its own position)."""
         cfg = self.cfg
-        x = jnp.take(params["embed/tok"], tokens, axis=0)
+        with jax.named_scope("embed"):
+            x = jnp.take(params["embed/tok"], tokens, axis=0)
         layer_params = self._layer_params(params)
         win = self.swa_window or cfg.sliding_window
 
@@ -473,13 +478,16 @@ class Model:
 
 def _attn_with_kv(model: Model, lp, h, positions, win):
     cfg, geom, pset = model.cfg, model.geom, model.pset
-    q, k, v = attn_mod._proj_qkv(cfg, geom, pset, lp, h)
     from repro.models.common import rotate
-    q = rotate(cfg, q.reshape(*q.shape[:2], -1, geom.head_dim), positions
-               ).reshape(q.shape)
-    k = rotate(cfg, k, positions)
-    o = attn_mod.flash_attention(q, k, v, causal=cfg.causal, window=win)
-    return attn_mod._out_proj(geom, pset, lp, o), (k, v)
+    with jax.named_scope("attention/qkv"):
+        q, k, v = attn_mod._proj_qkv(cfg, geom, pset, lp, h)
+        q = rotate(cfg, q.reshape(*q.shape[:2], -1, geom.head_dim),
+                   positions).reshape(q.shape)
+        k = rotate(cfg, k, positions)
+    with jax.named_scope("attention/core"):
+        o = attn_mod.flash_attention(q, k, v, causal=cfg.causal, window=win)
+    with jax.named_scope("attention/out"):
+        return attn_mod._out_proj(geom, pset, lp, o), (k, v)
 
 
 def _kv_to_cache(kv, alen: int):
@@ -503,6 +511,7 @@ def _kv_to_cache(kv, alen: int):
     return {"k": place(k, 0), "v": place(v, 0), "pos": place(pos, -1)}
 
 
+@jax.named_scope("ssm")
 def _ssm_with_state(model: Model, lp, h):
     cfg, pset = model.cfg, model.pset
     B, S, _ = h.shape

@@ -86,11 +86,13 @@ def make_train_step(built: Built, opt_cfg: Optional[AdamWConfig] = None,
 
     def step(params, opt_state: AdamWState, batch):
         loss, metrics, grads = loss_and_grads(model, params, batch, micro)
-        if overlap is not None and overlap.bucket_bytes > 0:
-            grads = _bucket_grads(grads, overlap.bucket_bytes)
-        lr_scale = warmup_cosine(opt_state.step + 1, warmup, total_steps)
-        params, opt_state, opt_metrics = apply_update(
-            opt_cfg, params, grads, opt_state, lr_scale)
+        with jax.named_scope("optimizer"):
+            if overlap is not None and overlap.bucket_bytes > 0:
+                grads = _bucket_grads(grads, overlap.bucket_bytes)
+            lr_scale = warmup_cosine(opt_state.step + 1, warmup,
+                                     total_steps)
+            params, opt_state, opt_metrics = apply_update(
+                opt_cfg, params, grads, opt_state, lr_scale)
         metrics = dict(metrics, loss=loss, **opt_metrics)
         return params, opt_state, metrics
 

@@ -10,6 +10,7 @@ unnoticed.  To change the inventory intentionally, update
 EXPECTED_SKIP_MODULES / EXPECTED_XFAILS below in the same PR.
 """
 import dataclasses
+import re
 
 import jax
 import numpy as np
@@ -132,3 +133,48 @@ def make_batch(cfg, B, S, key=0):
         "tokens": jax.random.randint(k, (B, S), 0, cfg.vocab_size),
         "labels": jax.random.randint(k, (B, S), 0, cfg.vocab_size),
     }
+
+
+# --- the program's layer scopes in a compiled step ---------------------------
+LAYER_SCOPES = ("embed", "norm", "attention/qkv", "attention/core",
+                "attention/out", "ffn", "moe", "ssm", "loss", "optimizer")
+
+
+def hlo_op_names(hlo_text: str, ops=None) -> dict:
+    """Instruction name -> op_name of the optimized HLO text, for the
+    instructions whose opcode is in `ops` (all where None)."""
+    found = re.findall(r'(?m)^\s*(?:ROOT )?%([\w.\-]+) = [^\n]*? ([\w\-]+)\('
+                       r'[^\n]*?op_name="([^"]*)"', hlo_text)
+    return {name: op for name, opcode, op in found
+            if ops is None or opcode in ops}
+
+
+def scopes_of(op_name: str) -> list:
+    """The layer scopes an op_name names, transform wrappers such as
+    `transpose(jvp(loss))` included."""
+    return [s for s in LAYER_SCOPES
+            if re.search(r"(^|[/(])" + re.escape(s) + r"($|[/)])", op_name)]
+
+
+def step_passes(hlo_text: str) -> set:
+    """The passes whose instructions the compiled train step holds:
+    forward (`jvp(`), recompute (`rematted_computation`), backward
+    (`transpose(`) and optimizer (the `optimizer` scope)."""
+    out = set()
+    for op in hlo_op_names(hlo_text).values():
+        if "optimizer" in scopes_of(op):
+            out.add("optimizer")
+        elif "rematted_computation" in op:
+            out.add("recompute")
+        elif "transpose(" in op:
+            out.add("backward")
+        elif "jvp(" in op:
+            out.add("forward")
+    return out
+
+
+def unscoped_matmuls(hlo_text: str) -> list:
+    """op_names of the dot and convolution instructions that carry no
+    layer scope."""
+    return sorted(op for op in hlo_op_names(
+        hlo_text, ("dot", "convolution")).values() if not scopes_of(op))

@@ -15,6 +15,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
 
+from conftest import step_passes, unscoped_matmuls
 from repro.configs import (DeviceInfo, MeshConfig, OSDPConfig, RunConfig,
                            get_arch, get_shape)
 from repro.core.plan import make_plan
@@ -118,9 +119,10 @@ def _qwen_run(mesh_cfg, *, batch, seq, force_mode=None):
                                      memory_limit_bytes=V5E.hbm_bytes))
 
 
-def test_train_step_fits_one_chip(one_chip):
+@pytest.fixture(scope="module")
+def train_step_one_chip(one_chip):
     """Full-width qwen1.5-0.5b, its searched plan, AdamW, batch 2 x
-    4096: the one-chip train step compiles and fits in HBM."""
+    4096: the one-chip train step, compiled once for the tests below."""
     run = _qwen_run(MeshConfig((1, 1), ("data", "model")), batch=2,
                     seq=4096)
     built = build_model(run, make_plan(run, V5E), None)
@@ -128,8 +130,22 @@ def test_train_step_fits_one_chip(one_chip):
     params = _with(built.abstract_params(), one_chip)
     opt = _with(jax.eval_shape(init_state, params), one_chip)
     batch = _with(train_inputs(run.model, 2, 4096), one_chip)
-    compiled = step_fn.lower(params, opt, batch).compile()
-    assert _fits(compiled) > 2**30
+    return step_fn.lower(params, opt, batch).compile()
+
+
+def test_train_step_fits_one_chip(train_step_one_chip):
+    """The one-chip train step compiles and fits in HBM."""
+    assert _fits(train_step_one_chip) > 2**30
+
+
+def test_train_step_layer_scopes(train_step_one_chip):
+    """Every matmul of the chip's optimized step names its layer, and
+    the forward, recomputed, backward and optimizer instructions are
+    all there to find."""
+    text = train_step_one_chip.as_text()
+    assert unscoped_matmuls(text) == []
+    assert step_passes(text) == {"forward", "recompute", "backward",
+                                 "optimizer"}
 
 
 def test_serve_steps_compile_one_chip(one_chip):
