@@ -11,6 +11,11 @@ the 32k/500k assigned shapes. The Pallas kernel in
 `repro.kernels.flash_attention` implements the same contract for the
 TPU hot path and is validated against the same oracle.
 
+Its backward is its own (`jax.custom_vjp`, FlashAttention-2's recipe):
+the forward saves q, k, v, the float32 output and each row's
+log-sum-exp, and the backward recomputes each block's scores and
+probabilities from them, so no per-block tensor outlives its block.
+
 Decode: the KV cache tags every slot with its absolute position
 (`pos`, -1 = empty), which makes full-cache and rolling sliding-window
 caches uniform: validity/window masking is pure position arithmetic.
@@ -40,21 +45,47 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     bq: int = 512, bk: int = 1024,
                     scale: Optional[float] = None) -> jax.Array:
     """q:(B,S,KV,G,hd) k,v:(B,T,KV,hd) -> (B,S,KV,G,hd)."""
+    scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    return _flash(q, k, v, causal, window, q_offset, min(bq, q.shape[1]),
+                  min(bk, k.shape[1]), scale)
+
+
+def _blocks(q, k, v, bq, bk):
+    """Zero-pad S/T to block multiples and stack the blocks on a leading
+    axis: q (nq,B,bq,KV,G,hd), k/v (nk,B,bk,KV,hd)."""
     B, S, KV, G, hd = q.shape
     T = k.shape[1]
-    scale = scale if scale is not None else 1.0 / math.sqrt(hd)
-    bq = min(bq, S)
-    bk = min(bk, T)
-    # pad S/T to block multiples
     Sp, Tp = -(-S // bq) * bq, -(-T // bk) * bk
     qp = jnp.pad(q, ((0, 0), (0, Sp - S), (0, 0), (0, 0), (0, 0)))
     kp = jnp.pad(k, ((0, 0), (0, Tp - T), (0, 0), (0, 0)))
     vp = jnp.pad(v, ((0, 0), (0, Tp - T), (0, 0), (0, 0)))
     nq, nk = Sp // bq, Tp // bk
-
     qb = jnp.moveaxis(qp.reshape(B, nq, bq, KV, G, hd), 1, 0)
     kb = jnp.moveaxis(kp.reshape(B, nk, bk, KV, hd), 1, 0)
     vb = jnp.moveaxis(vp.reshape(B, nk, bk, KV, hd), 1, 0)
+    return qb, kb, vb
+
+
+def _scores(q_blk, k_blk, q_pos, k_pos, *, T, causal, window, scale):
+    """Masked scaled scores of one block pair: (B,KV,G,bq,bk) float32."""
+    s = jnp.einsum("bqkgh,btkh->bkgqt", q_blk, k_blk,
+                   preferred_element_type=jnp.float32) * scale
+    msk = (k_pos[None, :] < T)
+    if causal:
+        msk = msk & (k_pos[None, :] <= q_pos[:, None])
+    if window:
+        msk = msk & (q_pos[:, None] - k_pos[None, :] < window)
+    return jnp.where(msk[None, None, None], s, NEG_INF)
+
+
+def _flash_forward(q, k, v, causal, window, q_offset, bq, bk, scale):
+    """The online-softmax scans: float32 output (B,S,KV,G,hd) and each
+    row's log-sum-exp (B,KV,G,S)."""
+    B, S, KV, G, hd = q.shape
+    score = partial(_scores, T=k.shape[1], causal=causal, window=window,
+                    scale=scale)
+    qb, kb, vb = _blocks(q, k, v, bq, bk)
+    nq, nk = qb.shape[0], kb.shape[0]
 
     def q_step(_, qi_blk):
         qi, q_blk = qi_blk
@@ -64,14 +95,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             kj, k_blk, v_blk = kj_blk
             m, l, acc = carry
             k_pos = kj * bk + jnp.arange(bk)
-            s = jnp.einsum("bqkgh,btkh->bkgqt", q_blk, k_blk,
-                           preferred_element_type=jnp.float32) * scale
-            msk = (k_pos[None, :] < T)
-            if causal:
-                msk = msk & (k_pos[None, :] <= q_pos[:, None])
-            if window:
-                msk = msk & (q_pos[:, None] - k_pos[None, :] < window)
-            s = jnp.where(msk[None, None, None], s, NEG_INF)
+            s = score(q_blk, k_blk, q_pos, k_pos)
             m_new = jnp.maximum(m, s.max(axis=-1))
             p = jnp.exp(s - m_new[..., None])
             corr = jnp.exp(m - m_new)
@@ -87,11 +111,87 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             k_step, (m0, l0, a0), (jnp.arange(nk), kb, vb))
         out = acc / jnp.maximum(l, 1e-30)[..., None]
         # (B,KV,G,bq,hd) -> (B,bq,KV,G,hd)
-        return None, jnp.moveaxis(out, 3, 1)
+        return None, (jnp.moveaxis(out, 3, 1), m + jnp.log(l))
 
-    _, ob = jax.lax.scan(q_step, None, (jnp.arange(nq), qb))
-    out = jnp.moveaxis(ob, 0, 1).reshape(B, Sp, KV, G, hd)[:, :S]
+    _, (ob, lse) = jax.lax.scan(q_step, None, (jnp.arange(nq), qb))
+    out = jnp.moveaxis(ob, 0, 1).reshape(B, nq * bq, KV, G, hd)[:, :S]
+    # (nq,B,KV,G,bq) -> (B,KV,G,S)
+    lse = jnp.moveaxis(lse, 0, 3).reshape(B, KV, G, nq * bq)[..., :S]
+    return out, lse
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, window, q_offset, bq, bk, scale):
+    out, _ = _flash_forward(q, k, v, causal, window, q_offset, bq, bk, scale)
     return out.astype(q.dtype)
+
+
+def _flash_fwd(q, k, v, causal, window, q_offset, bq, bk, scale):
+    out, lse = _flash_forward(q, k, v, causal, window, q_offset, bq, bk,
+                              scale)
+    return out.astype(q.dtype), (q, k, v, out, lse)
+
+
+def _flash_bwd(causal, window, q_offset, bq, bk, scale, res, d_out):
+    """FlashAttention-2's backward: per block pair, recompute the scores
+    and p = exp(s - lse), then dV += p^T dO, dP = dO V^T,
+    dS = p (dP - D) scale, dQ += dS K, dK += dS^T Q, with
+    D = rowsum(dO * O). Outer scan over key blocks (carrying dQ),
+    inner over query blocks (carrying this key block's dK, dV)."""
+    q, k, v, out, lse = res
+    B, S, KV, G, hd = q.shape
+    T = k.shape[1]
+    score = partial(_scores, T=T, causal=causal, window=window, scale=scale)
+    qb, kb, vb = _blocks(q, k, v, bq, bk)
+    nq, nk = qb.shape[0], kb.shape[0]
+    Sp = nq * bq
+
+    def q_major(x):
+        """(B,S,KV,G,...) -> (nq,B,KV,G,bq,...), zero-padded rows."""
+        x = jnp.pad(x, [(0, 0), (0, Sp - S)] + [(0, 0)] * (x.ndim - 2))
+        x = x.reshape(B, nq, bq, *x.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(x, 1, 0), 2, 4)
+
+    do = d_out.astype(jnp.float32)
+    dob = q_major(do)                                    # (nq,B,KV,G,bq,hd)
+    deltab = q_major((do * out).sum(axis=-1))            # (nq,B,KV,G,bq)
+    lse = jnp.pad(lse, ((0, 0), (0, 0), (0, 0), (0, Sp - S)))
+    lseb = jnp.moveaxis(lse.reshape(B, KV, G, nq, bq), 3, 0)
+
+    def k_step(dq, kj_blk):
+        kj, k_blk, v_blk = kj_blk
+        k_pos = kj * bk + jnp.arange(bk)
+        v32 = v_blk.astype(jnp.float32)
+
+        def q_step(carry, qi_blk):
+            dk, dv = carry
+            qi, q_blk, do_blk, lse_blk, delta_blk = qi_blk
+            q_pos = q_offset + qi * bq + jnp.arange(bq)
+            s = score(q_blk, k_blk, q_pos, k_pos)
+            p = jnp.exp(s - lse_blk[..., None])
+            dv = dv + jnp.einsum("bkgqt,bkgqh->btkh", p, do_blk)
+            dp = jnp.einsum("bkgqh,btkh->bkgqt", do_blk, v32)
+            ds = p * (dp - delta_blk[..., None]) * scale
+            dq_blk = jnp.einsum("bkgqt,btkh->bqkgh", ds, k_blk,
+                                preferred_element_type=jnp.float32)
+            dk = dk + jnp.einsum("bkgqt,bqkgh->btkh", ds, q_blk,
+                                 preferred_element_type=jnp.float32)
+            return (dk, dv), dq_blk
+
+        z = jnp.zeros((B, bk, KV, hd), jnp.float32)
+        (dk, dv), dq_j = jax.lax.scan(
+            q_step, (z, z), (jnp.arange(nq), qb, dob, lseb, deltab))
+        return dq + dq_j, (dk, dv)
+
+    dq0 = jnp.zeros(qb.shape, jnp.float32)
+    dq, (dk, dv) = jax.lax.scan(k_step, dq0, (jnp.arange(nk), kb, vb))
+    dq = jnp.moveaxis(dq, 0, 1).reshape(B, Sp, KV, G, hd)[:, :S]
+    dk = jnp.moveaxis(dk, 0, 1).reshape(B, nk * bk, KV, hd)[:, :T]
+    dv = jnp.moveaxis(dv, 0, 1).reshape(B, nk * bk, KV, hd)[:, :T]
+    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,

@@ -45,6 +45,23 @@ def test_train_step_scopes(arch):
                                   "optimizer"} <= named
 
 
+# the products only attention's own backward forms: dV, dP, dQ and dK
+ATTENTION_BACKWARD = ("bkgqt,bkgqh->btkh", "bkgqh,btkh->bkgqt",
+                      "bkgqt,btkh->bqkgh", "bkgqt,bqkgh->btkh")
+
+
+def test_attention_backward_scopes():
+    """The ops of attention's custom backward keep the `attention/core`
+    scope and carry the backward pass's `transpose(` mark."""
+    ops = hlo_op_names(compiled_step_text("qwen1.5-0.5b")).values()
+    for product in ATTENTION_BACKWARD:
+        found = [op for op in ops if f"/{product}/" in op]
+        assert found, product
+        for op in found:
+            assert scopes_of(op) == ["attention/core"], op
+            assert "transpose(" in op and "rematted_computation" not in op
+
+
 def test_scopes_of_reads_transform_wrappers():
     assert scopes_of("jit(step)/transpose(jvp(loss))/dot_general") == [
         "loss"]
