@@ -60,4 +60,7 @@ def supported_shapes(model: ModelConfig) -> list[str]:
     if model.is_decoder:
         names.append("decode_32k")
         names.append("long_500k")  # SWA/SSM path; see DESIGN.md §5
+    if model.max_positions:      # LongRoPE past its short factors: refused
+        names = [n for n in names
+                 if SHAPES[n].seq_len <= model.max_positions]
     return names

@@ -44,8 +44,20 @@ class ModelConfig:
     head_dim: int = 0       # 0 -> d_model // n_heads
     # --- attention options -------------------------------------------------
     qkv_bias: bool = False
-    rope: str = "rope"      # "rope" | "mrope" | "none"
+    rope: str = "rope"      # "rope" | "longrope" | "mrope" | "none"
     rope_theta: float = 10_000.0
+    # share of each head that the rotary embedding turns (Phi-3's
+    # partial_rotary_factor): the first `rotary_dim` dims rotate, the
+    # rest pass through
+    rotary_fraction: float = 1.0
+    # LongRoPE ("longrope"): up to `rope_original_max` positions each
+    # rotated pair's inverse frequency is divided by its short factor,
+    # and cos and sin are multiplied by `rope_attention_factor`; the long
+    # factors of longer contexts are not held, so longer positions are
+    # refused
+    rope_short_factor: Tuple[float, ...] = ()
+    rope_original_max: int = 0
+    rope_attention_factor: float = 1.0
     mrope_sections: Tuple[int, int, int] = (16, 24, 24)  # t/h/w halves of head_dim/2
     sliding_window: int = 0  # 0 = full attention (native); >0 native SWA
     causal: bool = True      # False for encoder-only
@@ -63,6 +75,7 @@ class ModelConfig:
     # --- misc --------------------------------------------------------------
     act: str = "swiglu"     # "swiglu" | "gelu"
     norm: str = "rmsnorm"   # "rmsnorm" | "layernorm"
+    norm_eps: float = 0.0   # 0: the norm's default, 1e-6 RMS, 1e-5 layer
     tie_embeddings: bool = False
     encoder_only: bool = False
     vocab_pad_multiple: int = 256
@@ -78,6 +91,15 @@ class ModelConfig:
         if self.n_heads:
             return self.d_model // self.n_heads
         return 0
+
+    @property
+    def rotary_dim(self) -> int:
+        return int(self.resolved_head_dim * self.rotary_fraction)
+
+    @property
+    def max_positions(self) -> int:
+        """Positions the rotary embedding is defined for (0: any)."""
+        return self.rope_original_max if self.rope == "longrope" else 0
 
     @property
     def padded_vocab(self) -> int:
@@ -167,6 +189,14 @@ class ModelConfig:
             assert self.n_heads > 0 and self.n_kv_heads > 0
             assert self.n_heads % self.n_kv_heads == 0, (
                 f"{self.name}: GQA requires n_heads % n_kv_heads == 0")
+            assert 0 < self.rotary_dim <= self.resolved_head_dim \
+                and self.rotary_dim % 2 == 0, (
+                    f"{self.name}: rotary dims {self.rotary_dim}")
+        if self.rope == "longrope":
+            assert self.rope_original_max > 0
+            assert len(self.rope_short_factor) == self.rotary_dim // 2, (
+                f"{self.name}: {len(self.rope_short_factor)} short factors "
+                f"for {self.rotary_dim // 2} rotated pairs")
         if self.has_ssm:
             assert self.ssm_state > 0
             assert self.ssm_d_inner % self.ssm_head_dim == 0
@@ -498,6 +528,9 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
         small.update(ssm_state=16, ssm_head_dim=32, ssm_chunk=32)
     if cfg.sliding_window:
         small.update(sliding_window=64)
+    if cfg.rope_short_factor:
+        small.update(rope_short_factor=cfg.rope_short_factor[
+            :int(head_dim * cfg.rotary_fraction) // 2])
     small.update(overrides)
     out = dataclasses.replace(cfg, name=cfg.name + "-smoke", **small)
     out.validate()

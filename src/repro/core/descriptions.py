@@ -26,6 +26,11 @@ BYTES_PER_PARAM = 2          # bf16 working copy
 #   bf16 param (2) + bf16 grad (2) + fp32 master (4) + fp32 m (4) + fp32 v (4)
 STATE_BYTES_PER_PARAM = 16
 ACT_BYTES = 2                # bf16 activations
+# the loss projects the final hidden states onto the vocabulary in
+# blocks of LOSS_CHUNK positions (`Transformer.loss_fn`): one block's
+# fp32 logits and the bf16 product they come from are live at once
+LOSS_CHUNK = 512
+LOGIT_BYTES = 4 + ACT_BYTES
 
 # serving-cache layout constants (must match the runtime caches:
 # models.attention.init_kv_cache / models.ssm.init_ssm_cache — the
@@ -65,6 +70,10 @@ class OperatorDesc:
     # whether the runtime shards this op's cache over the TP axis
     # (SSD heads are model-sharded; GQA KV heads are replicated)
     cache_tp_sharded: bool = False
+    # bf16 copies of the weight held besides the working one: a table
+    # tied to the head is held again in the layout the head's matmul
+    # reads
+    layout_copies: int = 0
     # memory of the transiently *gathered* weight in ZDP mode (the §3.3
     # "gigantic tensor" peak); defaults to the full param bytes.
 
@@ -74,7 +83,8 @@ class OperatorDesc:
 
     @property
     def state_bytes(self) -> int:
-        return self.param_count * STATE_BYTES_PER_PARAM
+        return self.param_count * (STATE_BYTES_PER_PARAM
+                                   + self.layout_copies * BYTES_PER_PARAM)
 
     @property
     def eff_remat_layers(self) -> int:
@@ -130,6 +140,15 @@ class ModelDescription:
 
 def _matmul_flops(d_in: int, d_out: int) -> float:
     return 2.0 * d_in * d_out
+
+
+def loss_chunk(seq: int, padded_vocab: int) -> int:
+    """Positions whose logits the loss holds at once: LOSS_CHUNK where
+    the whole sequence's would be large and it divides, else all."""
+    if (seq % LOSS_CHUNK == 0 and seq > LOSS_CHUNK
+            and seq * padded_vocab >= 2**27):
+        return LOSS_CHUNK
+    return seq
 
 
 def describe(model: ModelConfig, shape: ShapeConfig,
@@ -263,6 +282,26 @@ def describe(model: ModelConfig, shape: ShapeConfig,
     # remat stores one d_model activation per layer boundary + embedding out
     resident = (L + 1) * d * ACT_BYTES
     return ModelDescription(model, shape, ops, resident)
+
+
+def with_tied_head(desc: ModelDescription) -> ModelDescription:
+    """The description as the program's training step runs it. A table
+    tied to the head is the head too: it carries the head's matmul, one
+    loss block's logits per sequence, and a copy of the table in the
+    layout the head's matmul reads. The paper's operator list (§3.1),
+    which `describe` gives, leaves a tied head out."""
+    model, shape = desc.model, desc.shape
+    if (not model.tie_embeddings or not model.is_decoder
+            or model.encoder_only or shape.kind != "train"):
+        return desc
+    V, d, seq = model.padded_vocab, model.d_model, shape.seq_len
+    ops = [dataclasses.replace(
+        op, flops_per_token=op.flops_per_token + _matmul_flops(d, V),
+        act_bytes_per_token=(op.act_bytes_per_token + V * LOGIT_BYTES
+                             * loss_chunk(seq, V) / seq),
+        layout_copies=1) if op.name == "embed.tok" else op
+        for op in desc.operators]
+    return dataclasses.replace(desc, operators=ops)
 
 
 def sanity_check(desc: ModelDescription) -> None:

@@ -16,7 +16,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.configs.base import DeviceInfo, OSDPConfig, RunConfig
 from repro.core.cost_model import (DP, ZDP, CostEnv, Decision, PlanCost,
                                    plan_cost, uniform_plan)
-from repro.core.descriptions import ModelDescription, describe
+from repro.core.descriptions import ModelDescription, describe, with_tied_head
 from repro.core.search import SearchResult, search_plan
 
 
@@ -77,7 +77,7 @@ def make_plan(run: RunConfig,
     constants; None keeps the scalar path byte-identical."""
     device = device or (cluster.device if cluster is not None
                         else DeviceInfo())
-    desc = describe(run.model, run.shape)
+    desc = with_tied_head(describe(run.model, run.shape))
     # selective remat searches from the no-remat base env; bool flags
     # keep the legacy global-checkpointing environment
     env = CostEnv(device, run.mesh,

@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.common import AttnGeom, rotate
+from repro.models.common import AttnGeom, check_positions, rotate
 from repro.sharding.specs import ParamSet, seg_matmul
 
 NEG_INF = -1e30
@@ -273,6 +273,7 @@ def attn_forward(cfg: ModelConfig, geom: AttnGeom, pset: ParamSet,
 def init_kv_cache(cfg: ModelConfig, geom: AttnGeom, batch: int,
                   cache_len: int, dtype=jnp.bfloat16) -> Dict[str, jax.Array]:
     """Per-layer stacked cache pytree (leading L axis)."""
+    check_positions(cfg, cache_len)
     L = cfg.n_layers
     return {
         "k": jnp.zeros((L, batch, cache_len, geom.n_kv, geom.head_dim), dtype),

@@ -86,25 +86,43 @@ def norm(cfg: ModelConfig, x: jax.Array, scale: jax.Array,
          bias: Optional[jax.Array] = None) -> jax.Array:
     if cfg.norm == "layernorm":
         assert bias is not None
-        return layernorm(x, scale, bias)
-    return rmsnorm(x, scale)
+        return layernorm(x, scale, bias, eps=cfg.norm_eps or 1e-5)
+    return rmsnorm(x, scale, eps=cfg.norm_eps or 1e-6)
 
 
 # --- rotary embeddings -------------------------------------------------------
 
-def rope_freqs(head_dim: int, theta: float) -> jax.Array:
+def rope_freqs(head_dim: int, theta: float,
+               factors: Tuple[float, ...] = ()) -> jax.Array:
+    """Inverse frequencies of the `head_dim // 2` rotated pairs, each
+    divided by its LongRoPE factor where `factors` are given."""
     half = head_dim // 2
-    return 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) * 2 / head_dim))
+    exps = jnp.arange(half, dtype=jnp.float32) * 2 / head_dim
+    if factors:
+        return 1.0 / (jnp.asarray(factors, jnp.float32) * theta ** exps)
+    return 1.0 / (theta ** exps)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, n, hd); positions: broadcastable to (..., S)."""
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               rotary_dim: int = 0, factors: Tuple[float, ...] = (),
+               scale: float = 1.0) -> jax.Array:
+    """x: (..., S, n, hd); positions: broadcastable to (..., S).
+
+    The first `rotary_dim` dims (all by default) rotate as two halves
+    paired (HF's `rotate_half` layout), the rest pass through; cos and
+    sin are multiplied by `scale` (LongRoPE's attention factor)."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                       # (hd/2,)
-    ang = positions[..., None].astype(jnp.float32) * freqs   # (..., S, hd/2)
+    rot = rotary_dim or hd
+    freqs = rope_freqs(rot, theta, factors)             # (rot/2,)
+    ang = positions[..., None].astype(jnp.float32) * freqs   # (..., S, rot/2)
     cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
+    xf = x.astype(jnp.float32)
+    x1, x2 = jnp.split(xf if rot == hd else xf[..., :rot], 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    if rot < hd:
+        out = jnp.concatenate([out, xf[..., rot:]], axis=-1)
     return out.astype(x.dtype)
 
 
@@ -148,9 +166,23 @@ def positions_for(cfg: ModelConfig, batch: dict, seq: int) -> jax.Array:
     return jnp.broadcast_to(jnp.arange(seq, dtype=jnp.int32), (b, seq))
 
 
+def check_positions(cfg: ModelConfig, n: int) -> None:
+    """Refuse `n` positions past those the rotary embedding is defined
+    for (LongRoPE's longer contexts need long factors it does not
+    hold)."""
+    if cfg.max_positions and n > cfg.max_positions:
+        raise ValueError(
+            f"{cfg.name}: {n} positions; its LongRoPE short factors cover "
+            f"{cfg.max_positions} and the long factors are not held")
+
+
 def rotate(cfg: ModelConfig, x: jax.Array, positions: jax.Array) -> jax.Array:
     if cfg.rope == "none":
         return x
     if cfg.rope == "mrope":
         return apply_mrope(x, positions, cfg.rope_theta, cfg.mrope_sections)
-    return apply_rope(x, positions, cfg.rope_theta)
+    if cfg.rope == "longrope":
+        check_positions(cfg, positions.shape[-1])
+        return apply_rope(x, positions, cfg.rope_theta, cfg.rotary_dim,
+                          cfg.rope_short_factor, cfg.rope_attention_factor)
+    return apply_rope(x, positions, cfg.rope_theta, cfg.rotary_dim)

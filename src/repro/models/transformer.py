@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, RunConfig
 from repro.core.cost_model import DP, Decision
+from repro.core.descriptions import loss_chunk
 from repro.models import attention as attn_mod
 from repro.models import ffn as ffn_mod
 from repro.models import moe as moe_mod
@@ -320,10 +321,9 @@ class Model:
         # chunk the vocab projection over the sequence so the fp32
         # (B, S, V) logits never fully materialize (beyond-paper;
         # matters for the 128k-200k vocab archs at seq 4k)
-        chunk = 512
+        chunk = loss_chunk(S, cfg.padded_vocab)
         with jax.named_scope("loss"):
-            if (S % chunk == 0 and S > chunk
-                    and S * cfg.padded_vocab >= 2**27):
+            if chunk < S:
                 nb = S // chunk
                 xb = jnp.moveaxis(
                     x.reshape(x.shape[0], nb, chunk, x.shape[-1]), 1, 0)

@@ -21,6 +21,7 @@ multi-pod mesh, ZDP uses ('pod','data') and ZDP_POD only 'data'.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
@@ -388,19 +389,61 @@ def saved_activation_names(layouts: Dict[str, SegLayout],
 
 # --- helpers used by model forward passes -----------------------------------
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _zdp_gather(w: jax.Array, whole: NamedSharding,
+                split: NamedSharding) -> jax.Array:
+    with jax.named_scope("zdp/gather"):
+        return jax.lax.with_sharding_constraint(w, whole)
+
+
+def _zdp_gather_fwd(w, whole, split):
+    return _zdp_gather(w, whole, split), None
+
+
+def _zdp_gather_bwd(whole, split, _, g):
+    # the gradient, summed over the data axes, lands split as the
+    # weight is: a reduce-scatter, not an all-reduce of the whole
+    return (jax.lax.with_sharding_constraint(g, split),)
+
+
+_zdp_gather.defvjp(_zdp_gather_fwd, _zdp_gather_bwd)
+
+
+def _gathered(w: jax.Array, pset: ParamSet, path: str,
+              leaf: str) -> jax.Array:
+    """A segment's weight as its operator uses it: a ZDP segment is
+    gathered whole on every device of its data axes (`zdp/gather`), and
+    its gradient reduce-scattered back; a DP segment, or one of a build
+    without a mesh, passes as it is."""
+    sh = pset.shardings.get(leaf)
+    spec = pset.layouts[path].spec
+    if sh is None or spec.zdp_axis is None:
+        return w
+    parts = list(sh.spec) + [None] * (len(spec.shape) - len(sh.spec))
+    if parts[spec.zdp_axis] is None:
+        return w
+    if spec.stacked and w.ndim == len(spec.shape) - 1:
+        parts = parts[1:]            # the layer scan's slice of the stack
+    whole = list(parts)
+    whole[spec.zdp_axis - (len(spec.shape) - w.ndim)] = None
+    return _zdp_gather(w, NamedSharding(sh.mesh, P(*whole)),
+                       NamedSharding(sh.mesh, P(*parts)))
+
+
 def gather_weight(params: Dict[str, jax.Array], pset: ParamSet,
                   path: str) -> jax.Array:
     """Concatenate a weight's segments back (for ops that don't exploit
     sequential slice processing). Axis accounts for the layer axis being
     consumed when called inside the scan-over-layers body."""
     segs = pset.segments(path)
+    ws = [_gathered(params[k], pset, path, k) for k, _ in segs]
     if len(segs) == 1:
-        return params[segs[0][0]]
+        return ws[0]
     spec = pset.layouts[path].spec
     axis = spec.zdp_axis
-    if spec.stacked and params[segs[0][0]].ndim == len(spec.shape) - 1:
+    if spec.stacked and ws[0].ndim == len(spec.shape) - 1:
         axis -= 1
-    return jnp.concatenate([params[k] for k, _ in segs], axis=axis)
+    return jnp.concatenate(ws, axis=axis)
 
 
 def _prefetch_weights(ws: List[jax.Array], ahead: int) -> List[jax.Array]:
@@ -441,10 +484,9 @@ def seg_matmul(x: jax.Array, params: Dict[str, jax.Array], pset: ParamSet,
     spec = pset.layouts[path].spec
     # outputs are tagged with checkpoint_name so a selective-remat plan
     # compiles to a save_only_these_names policy (identity otherwise)
+    ws = [_gathered(params[leaf], pset, path, leaf) for leaf, _ in segs]
     if len(segs) == 1:
-        return checkpoint_name(
-            _contract(x, params[segs[0][0]], in_axis_in_weight), path)
-    ws = [params[leaf] for leaf, _ in segs]
+        return checkpoint_name(_contract(x, ws[0], in_axis_in_weight), path)
     if pset.overlap is not None and pset.overlap.prefetch > 0:
         ws = _prefetch_weights(ws, pset.overlap.prefetch)
     zdp_local = spec.zdp_axis - (1 if spec.stacked else 0)

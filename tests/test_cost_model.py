@@ -106,3 +106,41 @@ def test_zdp_pod_stays_on_fast_link():
     assert t_pod < t_flat
     # but saves less memory
     assert zdp_saving(op, ENV_POD, ZDP_POD) < zdp_saving(op, ENV_POD, ZDP)
+
+
+@pytest.mark.parametrize("arch,shape,counted", [
+    ("qwen1.5-0.5b", "train_4k", True),       # tied, training
+    ("phi4-mini-3.8b", "train_4k", True),     # tied, training
+    ("llama3-405b", "train_4k", False),       # its own head.out
+    ("qwen1.5-0.5b", "decode_32k", False),    # serving: no loss
+])
+def test_tied_head_counted_in_program_plans(arch, shape, counted):
+    """`with_tied_head` gives a tied table the head's matmul, one loss
+    block's fp32 + bf16 logits per sequence, and a bf16 copy of the
+    table in the head's layout; `make_plan` plans with it. Any other
+    description is returned as it is."""
+    from repro.configs import RunConfig
+    from repro.core.descriptions import (BYTES_PER_PARAM, LOSS_CHUNK,
+                                         STATE_BYTES_PER_PARAM,
+                                         with_tied_head)
+    from repro.core.plan import make_plan
+    model, shp = get_arch(arch), get_shape(shape)
+    base = describe(model, shp)
+    desc = with_tied_head(base)
+    if not counted:
+        assert desc is base
+        return
+    e0, e1 = base.operators[0], desc.operators[0]
+    assert e1.name == "embed.tok" and e1.layout_copies == 1
+    V, d = model.padded_vocab, model.d_model
+    assert e1.flops_per_token - e0.flops_per_token == 2.0 * d * V
+    assert (e1.act_bytes_per_token - e0.act_bytes_per_token) \
+        * shp.seq_len == pytest.approx(V * 6 * LOSS_CHUNK)
+    assert e1.state_bytes == e0.param_count * (STATE_BYTES_PER_PARAM
+                                               + BYTES_PER_PARAM)
+    assert desc.operators[1:] == base.operators[1:]
+    plan = make_plan(RunConfig(model=model, shape=shp,
+                               mesh=SINGLE_POD_MESH,
+                               osdp=OSDPConfig(memory_limit_bytes=2**40)),
+                     DeviceInfo())
+    assert plan.desc.operators[0] == e1

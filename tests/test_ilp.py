@@ -298,8 +298,10 @@ def test_search_plan_nodes_visited_populated_per_solver():
 
 def test_osdp_api_exposes_certificate():
     from repro.core import osdp
+    # between the fewest bytes a plan needs (2.65 GiB, its tied head
+    # counted) and all-DP's (3.06 GiB), so the limit binds
     plan = osdp(get_arch("qwen1.5-0.5b"), get_shape("train_4k"),
-                SINGLE_POD_MESH, memory_limit_gib=2.3, search="ilp",
+                SINGLE_POD_MESH, memory_limit_gib=2.8, search="ilp",
                 checkpointing=False)
     assert plan.search is not None and plan.search.feasible
     assert plan.search.proven_optimal is True
@@ -311,9 +313,11 @@ def test_osdp_api_exposes_certificate():
 def test_selective_remat_ilp_dominates_truncated_dfs_and_greedy():
     """The case the audit was built for (PR 3 selective checkpointing):
     on the 4-mode phi4 per-layer problem at 16 GiB the dfs runs with a
-    10k-node cap (the unbudgeted search does not terminate in minutes
-    on a problem the ILP closes in milliseconds), so its plan carries a
-    real gap — measured 2.27% — and greedy's heuristic gap is 8.79%.
+    node cap (the unbudgeted search does not terminate in minutes on a
+    problem the ILP closes in milliseconds), so its plan carries a real
+    gap — measured 0.069% with the head tied to the embedding (2.27%
+    with the untied head) — and greedy's heuristic gap is 20.1% (8.79%)
+    and knapsack's 11.1%.
     Pin both: the ILP must strictly dominate, and the measured gaps
     must stay in their bands (a collapse to 0 means the budget cap
     silently moved; a blow-up means a solver regressed)."""
@@ -332,9 +336,9 @@ def test_selective_remat_ilp_dominates_truncated_dfs_and_greedy():
     assert res["ilp"].proven_optimal is True
     gap = {s: res[s].cost.time / t_ilp - 1.0 for s in SOLVERS}
     # ILP strictly dominates the truncated dfs and the greedy heuristic
-    assert 0.01 < gap["dfs"] < 0.05, gap
-    assert 0.05 < gap["greedy"] < 0.12, gap
-    assert -2e-3 <= gap["knapsack"] < 0.03, gap
+    assert 2e-4 < gap["dfs"] < 2e-3, gap
+    assert 0.15 < gap["greedy"] < 0.25, gap
+    assert 0.08 < gap["knapsack"] < 0.14, gap
     assert gap["greedy"] > gap["dfs"]
 
 
