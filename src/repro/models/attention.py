@@ -5,11 +5,16 @@ Layouts (see common.AttnGeom):
   k/v: (B, T, KV, hd)     — kv heads replicated over the model axis
 
 The training/prefill path is an online-softmax blockwise ("flash")
-attention written in pure jnp with `lax.scan` over query and key
-blocks, so the (S, T) score matrix never materializes — mandatory at
-the 32k/500k assigned shapes. The Pallas kernel in
-`repro.kernels.flash_attention` implements the same contract for the
-TPU hot path and is validated against the same oracle.
+attention written in pure jnp, so the (S, T) score matrix never
+materializes — mandatory at the 32k/500k assigned shapes. An outer
+`lax.scan` over query blocks (key blocks in the backward) runs an inner
+loop over only the block pairs the mask leaves an element of:
+`_live_range` gives each outer block its contiguous range of inner
+blocks from `causal`, `window`, `q_offset` and the shapes, so a causal
+4096-token sequence at 512 x 1024 blocks visits 20 of its 32 pairs.
+The Pallas kernel in `repro.kernels.flash_attention` implements the
+same contract for the TPU hot path and is validated against the same
+oracle.
 
 Its backward is its own (`jax.custom_vjp`, FlashAttention-2's recipe):
 the forward saves q, k, v, the float32 output and each row's
@@ -78,22 +83,60 @@ def _scores(q_blk, k_blk, q_pos, k_pos, *, T, causal, window, scale):
     return jnp.where(msk[None, None, None], s, NEG_INF)
 
 
+def _live_range(outer, *, by_key, causal, window, q_offset, S, T, bq, bk):
+    """The contiguous range [start, stop) of inner blocks holding at
+    least one element `_scores` leaves unmasked against outer block
+    `outer` (the one traced value; every other argument is a Python
+    int). by_key=False: `outer` is a query block, the range covers key
+    blocks; by_key=True: the reverse.
+
+    Query row r sits at q_offset + r, key column c at c, and `_scores`
+    keeps c < T, c <= q (causal) and q - c < window (window > 0): the
+    distance d = q - c lies in [d_lo, d_hi]. So query rows [a, b] see
+    key columns [a + q_offset - d_hi, b + q_offset - d_lo], and key
+    columns [a, b] are seen by query rows [a + d_lo - q_offset,
+    b + d_hi - q_offset]; each clipped to the real rows or columns."""
+    far = S + T + abs(q_offset)             # beyond any distance
+    d_lo = 0 if causal else -far
+    d_hi = window - 1 if window else far
+    if by_key:
+        n_out, b_out, n_in, b_in = T, bk, S, bq
+        lo, hi = d_lo - q_offset, d_hi - q_offset
+    else:
+        n_out, b_out, n_in, b_in = S, bq, T, bk
+        lo, hi = q_offset - d_hi, q_offset - d_lo
+    a = outer * b_out
+    b = jnp.minimum(a + b_out, n_out) - 1
+    first = jnp.maximum(a + lo, 0)
+    last = jnp.minimum(b + hi, n_in - 1)
+    start = first // b_in
+    return start, jnp.where(first <= last, last // b_in + 1, start)
+
+
 def _flash_forward(q, k, v, causal, window, q_offset, bq, bk, scale):
-    """The online-softmax scans: float32 output (B,S,KV,G,hd) and each
-    row's log-sum-exp (B,KV,G,S)."""
+    """The online softmax: float32 output (B,S,KV,G,hd) and each row's
+    log-sum-exp (B,KV,G,S). An outer scan over query blocks; for each,
+    a loop over only the key blocks `_live_range` gives it, each pair
+    still masked by `_scores`. A skipped pair would add exactly nothing:
+    with the causal mask its p is exp(NEG_INF - m) = 0, and a window's
+    first live block wipes what came before it with corr = 0."""
     B, S, KV, G, hd = q.shape
-    score = partial(_scores, T=k.shape[1], causal=causal, window=window,
+    T = k.shape[1]
+    score = partial(_scores, T=T, causal=causal, window=window,
                     scale=scale)
+    live = partial(_live_range, by_key=False, causal=causal, window=window,
+                   q_offset=q_offset, S=S, T=T, bq=bq, bk=bk)
     qb, kb, vb = _blocks(q, k, v, bq, bk)
-    nq, nk = qb.shape[0], kb.shape[0]
+    nq = qb.shape[0]
 
     def q_step(_, qi_blk):
         qi, q_blk = qi_blk
         q_pos = q_offset + qi * bq + jnp.arange(bq)
 
-        def k_step(carry, kj_blk):
-            kj, k_blk, v_blk = kj_blk
+        def k_step(kj, carry):
             m, l, acc = carry
+            k_blk = jax.lax.dynamic_index_in_dim(kb, kj, keepdims=False)
+            v_blk = jax.lax.dynamic_index_in_dim(vb, kj, keepdims=False)
             k_pos = kj * bk + jnp.arange(bk)
             s = score(q_blk, k_blk, q_pos, k_pos)
             m_new = jnp.maximum(m, s.max(axis=-1))
@@ -102,13 +145,12 @@ def _flash_forward(q, k, v, causal, window, q_offset, bq, bk, scale):
             l_new = l * corr + p.sum(axis=-1)
             acc_new = acc * corr[..., None] + jnp.einsum(
                 "bkgqt,btkh->bkgqh", p, v_blk.astype(jnp.float32))
-            return (m_new, l_new, acc_new), None
+            return m_new, l_new, acc_new
 
         m0 = jnp.full((B, KV, G, bq), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, KV, G, bq), jnp.float32)
         a0 = jnp.zeros((B, KV, G, bq, hd), jnp.float32)
-        (m, l, acc), _ = jax.lax.scan(
-            k_step, (m0, l0, a0), (jnp.arange(nk), kb, vb))
+        m, l, acc = jax.lax.fori_loop(*live(qi), k_step, (m0, l0, a0))
         out = acc / jnp.maximum(l, 1e-30)[..., None]
         # (B,KV,G,bq,hd) -> (B,bq,KV,G,hd)
         return None, (jnp.moveaxis(out, 3, 1), m + jnp.log(l))
@@ -136,12 +178,16 @@ def _flash_bwd(causal, window, q_offset, bq, bk, scale, res, d_out):
     """FlashAttention-2's backward: per block pair, recompute the scores
     and p = exp(s - lse), then dV += p^T dO, dP = dO V^T,
     dS = p (dP - D) scale, dQ += dS K, dK += dS^T Q, with
-    D = rowsum(dO * O). Outer scan over key blocks (carrying dQ),
-    inner over query blocks (carrying this key block's dK, dV)."""
+    D = rowsum(dO * O). An outer scan over key blocks; for each, a loop
+    over only the query blocks `_live_range` gives it, carrying this key
+    block's dK, dV and the float32 dQ, into which each pair adds its
+    block in place."""
     q, k, v, out, lse = res
     B, S, KV, G, hd = q.shape
     T = k.shape[1]
     score = partial(_scores, T=T, causal=causal, window=window, scale=scale)
+    live = partial(_live_range, by_key=True, causal=causal, window=window,
+                   q_offset=q_offset, S=S, T=T, bq=bq, bk=bk)
     qb, kb, vb = _blocks(q, k, v, bq, bk)
     nq, nk = qb.shape[0], kb.shape[0]
     Sp = nq * bq
@@ -163,9 +209,11 @@ def _flash_bwd(causal, window, q_offset, bq, bk, scale, res, d_out):
         k_pos = kj * bk + jnp.arange(bk)
         v32 = v_blk.astype(jnp.float32)
 
-        def q_step(carry, qi_blk):
-            dk, dv = carry
-            qi, q_blk, do_blk, lse_blk, delta_blk = qi_blk
+        def q_step(qi, carry):
+            dq, dk, dv = carry
+            q_blk, do_blk, lse_blk, delta_blk = (
+                jax.lax.dynamic_index_in_dim(x, qi, keepdims=False)
+                for x in (qb, dob, lseb, deltab))
             q_pos = q_offset + qi * bq + jnp.arange(bq)
             s = score(q_blk, k_blk, q_pos, k_pos)
             p = jnp.exp(s - lse_blk[..., None])
@@ -176,12 +224,14 @@ def _flash_bwd(causal, window, q_offset, bq, bk, scale, res, d_out):
                                 preferred_element_type=jnp.float32)
             dk = dk + jnp.einsum("bkgqt,bqkgh->btkh", ds, q_blk,
                                  preferred_element_type=jnp.float32)
-            return (dk, dv), dq_blk
+            dq = jax.lax.dynamic_update_index_in_dim(
+                dq, jax.lax.dynamic_index_in_dim(dq, qi, keepdims=False)
+                + dq_blk, qi, 0)
+            return dq, dk, dv
 
         z = jnp.zeros((B, bk, KV, hd), jnp.float32)
-        (dk, dv), dq_j = jax.lax.scan(
-            q_step, (z, z), (jnp.arange(nq), qb, dob, lseb, deltab))
-        return dq + dq_j, (dk, dv)
+        dq, dk, dv = jax.lax.fori_loop(*live(kj), q_step, (dq, z, z))
+        return dq, (dk, dv)
 
     dq0 = jnp.zeros(qb.shape, jnp.float32)
     dq, (dk, dv) = jax.lax.scan(k_step, dq0, (jnp.arange(nk), kb, vb))

@@ -4,7 +4,9 @@
 log-sum-exp, and recomputes each block's probabilities on the backward
 pass. Its gradients must match `jax.grad` of the naive `attention_ref`
 and, within float32 round-off, `jax.vjp` of the forward-only scan it
-replaced (`_scan_attention` below, kept here as the oracle).
+replaced (`_scan_attention` below, kept here as the oracle), which
+visits every block pair where `flash_attention` visits only the pairs
+`_live_range` schedules.
 """
 import math
 
@@ -13,7 +15,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.models.attention import NEG_INF, attention_ref, flash_attention
+from repro.models.attention import (NEG_INF, _live_range, _scores,
+                                    attention_ref, flash_attention)
 
 
 def _scan_attention(q, k, v, *, causal, window=0, q_offset=0, bq=512,
@@ -70,7 +73,7 @@ def _scan_attention(q, k, v, *, causal, window=0, q_offset=0, bq=512,
     return out.astype(q.dtype)
 
 
-# name -> shapes, mask, blocks, dtype, oracle; `group` < G leaves the
+# name -> shapes, mask, blocks, dtype, oracles; `group` < G leaves the
 # last heads of each group as padding, zeroed as `_out_proj` zeroes them
 CASES = {
     "causal": dict(S=64, T=64, causal=True),
@@ -89,9 +92,20 @@ CASES = {
     "checkpoint_scan_bf16": dict(S=50, T=50, causal=True, layers=2,
                                  dtype=jnp.bfloat16),
     "old_scan_f32": dict(S=50, T=50, causal=True, window=20, G=3, group=2,
-                         oracle="scan"),
+                         oracles=("scan",)),
     "old_scan_bf16": dict(S=64, T=64, causal=True, dtype=jnp.bfloat16,
-                          oracle="scan"),
+                          oracles=("scan",)),
+    # small blocks, so that most block pairs are dead and skipped
+    "dead_pairs_causal": dict(S=256, T=256, causal=True, bq=32, bk=64,
+                              oracles=("ref", "scan")),
+    "dead_pairs_bq_over_bk": dict(S=192, T=192, causal=True, bq=64, bk=16,
+                                  oracles=("ref", "scan")),
+    "dead_pairs_window_offset_ragged": dict(
+        S=150, T=200, causal=True, window=20, q_offset=50, bq=32, bk=64,
+        oracles=("ref", "scan")),
+    "dead_pairs_checkpoint_scan": dict(S=256, T=256, causal=True, bq=32,
+                                       bk=64, layers=2,
+                                       oracles=("ref", "scan")),
 }
 
 
@@ -135,22 +149,86 @@ def test_flash_attention_grads(case):
     q, k, v, w = _inputs(c)
     got = _grads(lambda *a, **m: flash_attention(*a, **m, **blocks),
                  c, q, k, v, w)
-    if c.get("oracle") == "scan":
-        want = _grads(lambda *a, **m: _scan_attention(*a, **m, **blocks),
-                      c, q, k, v, w)
-    else:
-        want = _grads(attention_ref, c, q, k, v, w)
     bf16 = c.get("dtype") == jnp.bfloat16
-    for name, g, r in zip("qkv", got, want):
-        assert g.dtype == r.dtype == q.dtype, name
-        g = np.asarray(g, np.float32)
-        r = np.asarray(r, np.float32)
-        scale = float(np.abs(r).max())
-        assert scale > 0, name
-        if bf16:   # a few bf16 roundings of each gradient's terms
-            tol = 2e-2 * scale
-        elif c.get("oracle") == "scan":   # float32 round-off
-            tol = 2e-6 * scale
+    for oracle in c.get("oracles", ("ref",)):
+        if oracle == "scan":
+            want = _grads(
+                lambda *a, **m: _scan_attention(*a, **m, **blocks),
+                c, q, k, v, w)
         else:
-            tol = 2e-5 * scale
-        np.testing.assert_allclose(g, r, atol=tol, rtol=0, err_msg=name)
+            want = _grads(attention_ref, c, q, k, v, w)
+        for name, g, r in zip("qkv", got, want):
+            assert g.dtype == r.dtype == q.dtype, name
+            g = np.asarray(g, np.float32)
+            r = np.asarray(r, np.float32)
+            scale = float(np.abs(r).max())
+            assert scale > 0, name
+            if bf16:   # a few bf16 roundings of each gradient's terms
+                tol = 2e-2 * scale
+            elif oracle == "scan":   # float32 round-off
+                tol = 2e-6 * scale
+            else:
+                tol = 2e-5 * scale
+            np.testing.assert_allclose(g, r, atol=tol, rtol=0,
+                                       err_msg=f"{name} against {oracle}")
+
+
+def _live_pairs(*, causal, window, q_offset, S, T, bq, bk):
+    """Brute force: (nq, nk) bools, a pair live iff `_scores` leaves any
+    element of it unmasked between a real query row and a key column."""
+    nq, nk = -(-S // bq), -(-T // bk)
+    q_pos = q_offset + jnp.arange(nq * bq)
+    k_pos = jnp.arange(nk * bk)
+    q = jnp.zeros((1, nq * bq, 1, 1, 1))
+    k = jnp.zeros((1, nk * bk, 1, 1))
+    s = _scores(q, k, q_pos, k_pos, T=T, causal=causal, window=window,
+                scale=1.0)
+    seen = np.asarray(s[0, 0, 0] > NEG_INF)[:S]
+    seen = np.pad(seen, ((0, nq * bq - S), (0, 0)))
+    return seen.reshape(nq, bq, nk, bk).any(axis=(1, 3))
+
+
+def _schedule(live, *, by_key, **shape):
+    """(nq, nk) bools of the pairs `_live_range` schedules, seen from the
+    query blocks (by_key=False) or from the key blocks."""
+    n_out = live.shape[1] if by_key else live.shape[0]
+    got = np.zeros(live.shape, bool)
+    for o in range(n_out):
+        start, stop = (int(x) for x in _live_range(o, by_key=by_key, **shape))
+        if by_key:
+            got[start:stop, o] = True
+        else:
+            got[o, start:stop] = True
+    return got
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("bq,bk", [(16, 32), (32, 32), (32, 16)])
+def test_live_range_matches_mask(causal, bq, bk):
+    """Both directions of the schedule give exactly the pairs that hold
+    an unmasked element, over windows, offsets and ragged shapes."""
+    for window in (0, 5, 20, 40):
+        for q_offset in (0, 24):
+            for S, T in ((64, 64), (45, 61), (40, 64), (64, 40)):
+                shape = dict(causal=causal, window=window, q_offset=q_offset,
+                             S=S, T=T, bq=bq, bk=bk)
+                live = _live_pairs(**shape)
+                for by_key in (False, True):
+                    np.testing.assert_array_equal(
+                        _schedule(live, by_key=by_key, **shape), live,
+                        err_msg=f"{shape} by_key={by_key}")
+
+
+def test_live_pairs_at_cell_shapes():
+    """Both train cells of the benchmark (2 x 4096 on one chip, 1 x 4096
+    a chip on four) run causal attention over 4096 tokens at the default
+    512 x 1024 blocks: 20 of each layer's 32 block pairs are live,
+    whichever way the schedule is read."""
+    shape = dict(causal=True, window=0, q_offset=0, S=4096, T=4096, bq=512,
+                 bk=1024)
+    live = _live_pairs(**shape)
+    assert live.shape == (8, 4)
+    assert int(live.sum()) == 20
+    for by_key in (False, True):
+        np.testing.assert_array_equal(_schedule(live, by_key=by_key,
+                                                **shape), live)
