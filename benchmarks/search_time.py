@@ -1,9 +1,8 @@
 """Search-engine timing (paper §3.2: 9–307 s for 98–194 operators).
 
-Times the three solvers (dfs / knapsack / greedy) at paper-scale
-per-layer granularity — including the two largest assigned
-architectures, llama3-405b (885 per-layer operators) and arctic-480b
-(353) — plus a full n_devices=64 `search_hybrid` factorization sweep.
+Times the planner's dfs search at paper-scale per-layer granularity —
+including the two largest assigned architectures, llama3-405b (885
+per-layer operators) and arctic-480b (353) — plus a full n_devices=64 `search_hybrid` factorization sweep.
 
 Results are written to ``BENCH_search.json`` at the repo root so the
 planner-latency trajectory is tracked across PRs:
@@ -16,7 +15,9 @@ planner-latency trajectory is tracked across PRs:
 The ``baseline`` section is measured once against the pre-optimization
 engine and committed; ``--record current`` (the default) refreshes only
 the ``current`` section, so speedups always compare against the same
-committed reference.  ``--quick`` runs a small case set for CI smoke
+committed reference.  A case's ``seconds`` is the total over the
+solvers it timed: the committed baseline and current totals also count
+two solvers since removed, so a refreshed case total times dfs alone.  ``--quick`` runs a small case set for CI smoke
 (``--check`` then fails the run if any case exceeds its generous
 wall-clock ceiling).
 """
@@ -37,7 +38,6 @@ from repro.core.descriptions import describe
 from repro.core.search import search_hybrid, search_plan
 
 JSON_PATH = Path(__file__).resolve().parent.parent / "BENCH_search.json"
-SOLVERS = ("dfs", "knapsack", "greedy")
 
 # generous wall-clock ceilings (seconds) for --check; ~20x headroom over
 # the optimized engine so CI only trips on a real regression
@@ -65,7 +65,7 @@ def _search_plan_cases(quick: bool, device: Optional[DeviceInfo] = None):
     tuples.
 
     The llama3-405b / arctic-480b limits sit between the all-DP and
-    all-ZDP+split memory of the per-layer description, so every solver
+    all-ZDP+split memory of the per-layer description, so the search
     does real work (cover search + repair) instead of short-circuiting.
     The selective-remat case times the 4-mode axis (DP/ZDP x
     remat/no-remat per slice) at per-layer granularity — the widest
@@ -98,25 +98,21 @@ def _search_plan_cases(quick: bool, device: Optional[DeviceInfo] = None):
 
 
 def _run_search_plan_case(name, desc, env, lim, batch, ckpt, out) -> dict:
-    solvers: Dict[str, dict] = {}
-    total = 0.0
-    for solver in SOLVERS:
-        osdp = OSDPConfig(search=solver, memory_limit_bytes=lim,
-                          operator_splitting=True,
-                          default_slice_granularity=4,
-                          checkpointing=ckpt)
-        t0 = time.perf_counter()
-        res = search_plan(desc, batch, env, osdp)
-        dt = time.perf_counter() - t0
-        total += dt
-        out(f"{name},{desc.n_operators},{solver},{dt:.3f},"
-            f"{res.cost.time * 1e3:.2f},{res.feasible},{res.nodes_visited}")
-        solvers[solver] = {"seconds": round(dt, 6),
-                           "step_time_ms": round(res.cost.time * 1e3, 3),
-                           "feasible": res.feasible,
-                           "nodes_visited": res.nodes_visited}
-    return {"seconds": round(total, 6), "n_operators": desc.n_operators,
-            "solvers": solvers}
+    osdp = OSDPConfig(search="dfs", memory_limit_bytes=lim,
+                      operator_splitting=True,
+                      default_slice_granularity=4,
+                      checkpointing=ckpt)
+    t0 = time.perf_counter()
+    res = search_plan(desc, batch, env, osdp)
+    dt = time.perf_counter() - t0
+    out(f"{name},{desc.n_operators},dfs,{dt:.3f},"
+        f"{res.cost.time * 1e3:.2f},{res.feasible},{res.nodes_visited}")
+    return {"seconds": round(dt, 6), "n_operators": desc.n_operators,
+            "solvers": {"dfs": {"seconds": round(dt, 6),
+                                "step_time_ms": round(res.cost.time * 1e3,
+                                                      3),
+                                "feasible": res.feasible,
+                                "nodes_visited": res.nodes_visited}}}
 
 
 def _run_hybrid_case(name, desc, device, n_devices, lim, batch, out,

@@ -1,19 +1,19 @@
-"""Solver optimality audit: every search backend vs the exact ILP.
+"""Solver optimality audit: the planner's dfs vs the exact ILP.
 
-OSDP's claim is *optimality* of the searched plan, but dfs / knapsack /
-greedy are engineered solvers whose bounds were asserted nowhere
-against ground truth (ROADMAP item 4).  This benchmark re-solves a
-model-zoo x memory-limit x batch grid with all four backends and
-scores each against the ``search="ilp"`` oracle (``repro.core.ilp``):
+OSDP's claim is *optimality* of the searched plan, but dfs is an
+engineered search whose bounds were asserted nowhere against ground
+truth (ROADMAP item 4).  This benchmark re-solves a model-zoo x
+memory-limit x batch grid with dfs and scores it against the
+``search="ilp"`` oracle (``repro.core.ilp``):
 
-    gap(solver) = step_time(solver) / step_time(ilp) - 1
+    gap(dfs) = step_time(dfs) / step_time(ilp) - 1
 
 recording per-row gaps, decision identity, and effort (nodes, seconds)
 into the ``"solver_audit"`` section of ``BENCH_search.json``.
 
 ``--check`` (CI gate) asserts the audit table:
 
-  * all four backends agree on feasibility, row by row;
+  * dfs and the ilp agree on feasibility, row by row;
   * the ilp proves optimality on every row (no time budget given);
   * dfs is *exact*: gap == 0 and decisions byte-identical to the ilp
     on every row where its node budget does not truncate — i.e. all
@@ -21,10 +21,8 @@ into the ``"solver_audit"`` section of ``BENCH_search.json``.
     budget-capped by design (PR 3, 10k nodes: the unbudgeted search
     does not terminate in minutes on problems the MILP closes in
     milliseconds) and carries a real, bounded gap — the audit records
-    it instead of leaving it folklore;
-  * knapsack's quantization gap and greedy's heuristic gap stay under
-    their ceilings;
-  * no solver beats the proven optimum (gap >= 0 up to evaluator
+    it instead of leaving it folklore, under its ceiling;
+  * dfs never beats the proven optimum (gap >= 0 up to evaluator
     repair noise).
 """
 from __future__ import annotations
@@ -47,14 +45,10 @@ JSON_PATH = Path(__file__).resolve().parent.parent / "BENCH_search.json"
 # --check ceilings on the relative step-time gap vs the ilp optimum.
 # dfs: exact on legacy rows (asserted == 0 there); the selective rows
 # run it budget-truncated, where the measured gap is ~2.3% — ceiling 5%.
-# knapsack's gap is its quantization loss (~0.5% legacy, ~2.1% on the
-# adaptive-quantum selective rows; exactness on the *quantized* problem
-# is asserted solver-level in tests/test_solver_oracle.py); greedy is
-# the unbounded heuristic, measured 8.8% worst-case on the grid.
-GAP_CEILINGS = {"dfs": 0.05, "knapsack": 0.03, "greedy": 0.10}
-# the ilp is exact w.r.t. the solvers' per-slice item model, which is
+GAP_CEILINGS = {"dfs": 0.05}
+# the ilp is exact w.r.t. the search's per-slice item model, which is
 # itself a (slightly optimistic) approximation of the PlanEvaluator —
-# a heuristic's different cover can evaluate up to ~0.1% cheaper
+# a truncated dfs's different cover can evaluate up to ~0.1% cheaper
 # through the evaluator, so "nobody beats the optimum" is asserted to
 # this model-vs-evaluator tolerance, not to float epsilon
 EVAL_TOL = 2e-3

@@ -1,8 +1,8 @@
 """Plan-search explorer: run the OSDP search across all 10 assigned
 architectures x memory limits and print the decision matrix — which
 operators the search shards, which slices it remats
-(checkpointing="selective"), where the memory/time frontier sits, and
-how the three solvers compare.
+(checkpointing="selective"), and where the memory/time frontier
+sits.
 
 Run:  PYTHONPATH=src python examples/search_plans.py
 """

@@ -406,9 +406,9 @@ PRESET_OVERLAP = {name: p.achievable_overlap
 # switch into a per-slice searched decision (DP/ZDP x remat/no-remat)
 SELECTIVE = "selective"
 
-# the Search Engine's interchangeable cover-problem solvers: three
-# engineered heuristics/exacts plus the explicit ILP oracle (ISSUE 6)
-SOLVERS = ("dfs", "knapsack", "greedy", "ilp")
+# the Search Engine's cover-problem solver (dfs) and its exact
+# integer-program oracle (ilp)
+SOLVERS = ("dfs", "ilp")
 ILP_BACKENDS = ("auto", "milp", "bnb")
 
 
@@ -430,10 +430,6 @@ class OSDPConfig:
     # sharding mode (4-mode axis; beyond paper)
     checkpointing: Union[bool, str] = True
     force_mode: Optional[str] = None         # "DP" | "ZDP": bypass search
-    # alias for `search` (the solver-facing name): OSDPConfig(
-    # solver="ilp") == OSDPConfig(search="ilp").  When set it overrides
-    # the `search` default; setting both to different values is an error.
-    solver: Optional[str] = None
     # --- ilp solver knobs (search="ilp" only) ------------------------------
     # anytime mode: > 0 caps each cover solve at this many seconds and
     # accepts the incumbent + proven bound; 0 = solve to optimality
@@ -441,13 +437,6 @@ class OSDPConfig:
     ilp_backend: str = "auto"                # one of ILP_BACKENDS
 
     def __post_init__(self):
-        if self.solver is not None:
-            if self.search != "dfs" and self.search != self.solver:
-                raise ValueError(
-                    f"search={self.search!r} and solver={self.solver!r} "
-                    f"disagree: `solver` is an alias for `search`, set "
-                    f"one of them")
-            object.__setattr__(self, "search", self.solver)
         if self.search not in SOLVERS:
             raise ValueError(
                 f"search={self.search!r}: unknown solver; "

@@ -46,8 +46,8 @@ def osdp(model: ModelConfig,
          profile: Optional["CalibrationProfile"] = None) -> Plan:
     """Search the optimal sharded-data-parallel plan (paper Alg. 1).
 
-    `search` picks the cover solver: "dfs" (paper Algorithm 1),
-    "knapsack", "greedy", or "ilp" — the exact integer-program oracle
+    `search` picks the cover solver: "dfs" (paper Algorithm 1), or
+    "ilp" — the exact integer-program oracle dfs is audited against
     (`core.ilp`); with `ilp_time_budget_s > 0` the ilp runs anytime,
     returning the incumbent plus a proven bound
     (`plan.search.lower_bound` / `.proven_optimal`), and `ilp_backend`

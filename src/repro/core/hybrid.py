@@ -16,7 +16,7 @@ script:
       PP — GPipe microbatching: bubble (pp-1)/(m+pp-1) and
            stage-boundary activation sends,
   * `slice_description` — the 1/(tp*pp) model residue the DP-dimension
-    solvers (dfs/knapsack/greedy) decide over,
+    search (dfs, or its ilp oracle) decides over,
   * `HybridPlan`      — `core.plan.Plan`'s hybrid sibling: the chosen
     factorization, GPipe stage boundaries, and the per-operator
     DP/ZDP decisions of the inner search.

@@ -1,4 +1,4 @@
-"""Exact ILP backend for the OSDP cover problem (the fourth solver).
+"""Exact ILP oracle for the OSDP cover problem (``search="ilp"``).
 
 The Search Engine's covering problem (``core/search.py``)
 
@@ -6,10 +6,10 @@ The Search Engine's covering problem (``core/search.py``)
     s.t. sum_i  savings_i[m_i]  >=  need,      m_i in modes(i) + {None}
 
 is a 0/1 multiple-choice knapsack-cover: every slice item picks at most
-one of its (mode, remat) choices.  The shipped dfs/knapsack/greedy
-solvers are heuristically engineered (branch ordering, quantization,
-ratio ranking) — this module solves the *same* problem as an explicit
-integer linear program, so their answers can be audited against a
+one of its (mode, remat) choices.  The planner's dfs is engineered
+(branch ordering, a ratio-greedy incumbent, pruning bounds) — this
+module solves the *same* problem as an explicit integer linear
+program, so its answers can be audited against a
 formulation whose optimality is a property of the model, not of the
 search implementation (ROADMAP item 4; cf. AutoDDL's offline
 near-optimal layout solves and scamp-ml's interchangeable z3 / MiniZinc
@@ -84,7 +84,7 @@ class ILPSolve:
     added over the all-base plan); ``lower_bound`` the proven minimum.
     ``optimal`` means the gap is closed (or infeasibility proven —
     then ``objective`` is inf and ``choice`` is the max-saving
-    fallback every other solver returns on uncoverable instances).
+    fallback the engine's repair escalates to on uncoverable instances).
     ``nodes`` is the backend's effort: branch-and-bound nodes expanded
     plus one per integer variable (so trivially-presolved instances
     still report their model size).
@@ -159,9 +159,9 @@ def _decode(items: Sequence, groups: List[_Group],
 
 
 def _max_saving_fallback(items: Sequence) -> List[Optional[str]]:
-    """The uncoverable-instance fallback every solver agrees on:
-    shard everything at its max-saving choice (the feasibility
-    frontier; ``_solve_once``'s repair escalates to the same plan)."""
+    """The uncoverable-instance fallback: shard everything at its
+    max-saving choice (the feasibility frontier; ``_solve_once``'s
+    repair escalates to the same plan)."""
     return [max(it.savings, key=it.savings.get) for it in items]
 
 

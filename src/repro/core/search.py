@@ -1,43 +1,35 @@
 """OSDP Search Engine + Scheduler (paper Algorithm 1).
 
-Four solvers over the same problem
+One search over the covering problem
     min_p  T(p, b)   s.t.  M(p, b) <= M_limit,  p_i in {DP, ZDP[, ZDP_POD]}
 
 With `OSDPConfig(checkpointing="selective")` the per-slice decision
 space widens to the 4-mode axis {DP, ZDP[, ZDP_POD]} x {remat,
 no-remat}: the base plan is all-DP-no-remat and every item offers
 remat'd variants of each sharding mode (plus remat-only), whose
-activation savings and recompute costs are batch-linear — the solvers
-stay unchanged, they just see more choices per item, materialized per
+activation savings and recompute costs are batch-linear — the search
+stays unchanged, it just sees more choices per item, materialized per
 batch candidate.  `checkpointing=True/False` keep the legacy global
 behaviour byte-for-byte.
 
-  * ``dfs``      — the paper's depth-first search with its two pruning
-                   rules (memory-exceeded, worse-than-incumbent), made
-                   exact-and-fast with branch-and-bound lower bounds,
-                   best-ratio branch ordering, and *group collapsing*:
-                   per-layer descriptions expose hundreds of slices with
-                   identical (saving, cost) signatures, and the search
-                   branches on how many of each signature to shard
-                   instead of which — same optimum, exponentially fewer
-                   nodes. Paper-faithful semantics: returns the same
-                   argmin as brute force.
-  * ``knapsack`` — beyond-paper exact solver: choosing ZDP for op i
-                   saves dM_i memory and costs dT_i time, so the problem
-                   is a 0/1 knapsack-cover; solved by a vectorized
-                   (numpy row-wise) DP over discretized memory savings
-                   with a compact int8 parent encoding. O(n * M/Q) cell
-                   relaxations with quantum Q.
-  * ``greedy``   — dT/dM ratio heuristic, O(n log n); near-optimal when
-                   savings are small relative to the gap (used to seed
-                   the DFS incumbent).
-  * ``ilp``      — the explicit integer-linear-program oracle
+  * ``dfs``      — the planner's solver: the paper's depth-first search
+                   with its two pruning rules (memory-exceeded,
+                   worse-than-incumbent), made exact-and-fast with
+                   branch-and-bound lower bounds, a dT/dM ratio-greedy
+                   incumbent, best-ratio branch ordering, and *group
+                   collapsing*: per-layer descriptions expose hundreds
+                   of slices with identical (saving, cost) signatures,
+                   and the search branches on how many of each
+                   signature to shard instead of which — same optimum,
+                   exponentially fewer nodes. Paper-faithful semantics:
+                   returns the same argmin as brute force.
+  * ``ilp``      — its oracle, the explicit integer-linear-program
                    (``core.ilp``): scipy's HiGHS MILP when available, a
                    dependency-free Lagrangian-bound branch-and-bound
                    otherwise.  Exact by construction rather than by
-                   search engineering — the reference the other three
-                   are audited against (``benchmarks/solver_audit.py``)
-                   — and *anytime* under ``OSDPConfig.ilp_time_budget_s``
+                   search engineering — the reference dfs is audited
+                   against (``benchmarks/solver_audit.py``) — and
+                   *anytime* under ``OSDPConfig.ilp_time_budget_s``
                    (incumbent + proven ``SearchResult.lower_bound``).
 
 Plan evaluation around the solvers goes through
@@ -137,10 +129,6 @@ class SearchResult:
     # for one fixed instance — pinned by tests/test_ilp.py):
     #   dfs      — branch-and-bound nodes expanded (0 when the root
     #              capacity prune proves the need uncoverable)
-    #   knapsack — DP cells relaxed (0 when round-down quantization
-    #              proves the quantized need uncoverable and the solve
-    #              short-circuits to the max-saving fallback)
-    #   greedy   — items ranked (= number of items, always)
     #   ilp      — integer variables + branch-and-bound nodes (HiGHS
     #              mip_node_count for the milp backend, best-first pops
     #              for the pure-Python bnb; >= 1 always — trivial and
@@ -300,7 +288,7 @@ def _best_ratio(it: SliceItem) -> float:
 
 
 # ---------------------------------------------------------------------------
-# Solver 1: the paper's DFS (branch and bound over signature groups, exact)
+# The solver: the paper's DFS (branch and bound over signature groups, exact)
 # ---------------------------------------------------------------------------
 
 def _solve_dfs(items: List[SliceItem], need: float,
@@ -464,92 +452,16 @@ def _solve_dfs(items: List[SliceItem], need: float,
 
 
 # ---------------------------------------------------------------------------
-# Solver 2: exact knapsack-cover DP (beyond paper), vectorized
-# ---------------------------------------------------------------------------
-
-def _solve_knapsack(items: List[SliceItem], need: float,
-                    quantum: float = 16 * 2**20
-                    ) -> Tuple[List[Optional[str]], int]:
-    """DP over discretized memory saving. Savings are rounded DOWN (so a
-    'covered' answer is truly feasible); `need` is rounded up.
-
-    The relaxation is row-vectorized with numpy: one strided
-    minimum-update per (item, mode) instead of a Python loop over every
-    cell, and the n x cap parent table is an int8 mode index (plus one
-    int per item for the saturated top cell) instead of a list of
-    (state, mode) tuples. Returns (choice, cells_relaxed).
-    """
-    n = len(items)
-    if need <= 0:
-        return [None] * n, 0
-    cap = int(-(-need // quantum))          # ceil
-    mode_lists = [list(it.savings) for it in items]
-    q_best = [max((int(sav // quantum) for sav in it.savings.values()),
-                  default=0) for it in items]
-    if sum(q_best) < cap:
-        # uncoverable even at full sharding (the saturating DP could
-        # never reach the cap cell): same fallback, without the table
-        return [max(it.savings, key=it.savings.get) for it in items], 0
-
-    INF = float("inf")
-    dp = np.full(cap + 1, INF)
-    dp[0] = 0.0
-    pmode = np.full((n, cap + 1), -1, dtype=np.int8)
-    pcap = np.full(n, -1, dtype=np.int64)   # source state for cap updates
-    cells = 0
-    for i, it in enumerate(items):
-        ndp = dp.copy()
-        row = pmode[i]
-        for mi, m in enumerate(mode_lists[i]):
-            q = int(it.savings[m] // quantum)
-            if q == 0:
-                continue
-            t = it.extra_time[m]
-            cells += int(np.isfinite(dp).sum())
-            if q <= cap and cap - q >= 1:
-                # exact targets: state s -> s + q for s in [0, cap-q)
-                cand = dp[:cap - q] + t
-                tgt = ndp[q:cap]
-                imp = cand < tgt
-                if imp.any():
-                    tgt[imp] = cand[imp]
-                    row[q:cap][imp] = mi
-            # states [max(0, cap-q), cap] all saturate into the cap
-            # cell; the winner is the first minimum (strict-improvement
-            # sweep order of the scalar implementation)
-            lo = max(0, cap - q)
-            window = dp[lo:]
-            j = int(np.argmin(window))
-            v = window[j] + t
-            if v < ndp[cap]:
-                ndp[cap] = v
-                row[cap] = mi
-                pcap[i] = lo + j
-        dp = ndp
-    if not np.isfinite(dp[cap]):
-        return [max(it.savings, key=it.savings.get) for it in items], cells
-    # backtrack
-    choice: List[Optional[str]] = [None] * n
-    s = cap
-    for i in range(n - 1, -1, -1):
-        mi = int(pmode[i, s])
-        if mi < 0:
-            continue
-        m = mode_lists[i][mi]
-        choice[i] = m
-        if s == cap:
-            s = int(pcap[i])
-        else:
-            s -= int(items[i].savings[m] // quantum)
-    return choice, cells
-
-
-# ---------------------------------------------------------------------------
-# Solver 3: greedy ratio heuristic
+# dfs's incumbent: the dT/dM ratio greedy
 # ---------------------------------------------------------------------------
 
 def _solve_greedy(items: List[SliceItem],
                   need: float) -> Tuple[List[Optional[str]], float]:
+    """Take items in dT/dM ratio order until the need is covered.
+
+    Not a search choice: `_solve_dfs` seeds its incumbent with it, and
+    returns it as the fallback when the need cannot be covered (its
+    time is then inf). Returns (choice, time)."""
     n = len(items)
     choice: List[Optional[str]] = [None] * n
     if need <= 0:
@@ -648,8 +560,7 @@ class _SearchContext:
     def _solve_once(self, global_batch: int, items: List[SliceItem],
                     item_slice: np.ndarray, base_modes: np.ndarray,
                     need: float, state_map, solver: str,
-                    node_budget: int,
-                    quantum: Optional[float] = None) -> SearchResult:
+                    node_budget: int) -> SearchResult:
         """One covering solve + repair on a prepared problem.
 
         `base_modes` is the extended-mode array the choices overlay;
@@ -661,12 +572,6 @@ class _SearchContext:
         ilp = None
         if solver == "dfs":
             choice, nodes = _solve_dfs(items, need, node_budget)
-        elif solver == "knapsack":
-            choice, nodes = (_solve_knapsack(items, need, quantum)
-                             if quantum else _solve_knapsack(items, need))
-        elif solver == "greedy":
-            choice, _ = _solve_greedy(items, need)
-            nodes = len(items)
         elif solver == "ilp":
             ilp = solve_ilp(items, need,
                             time_budget=self.osdp.ilp_time_budget_s,
@@ -777,23 +682,9 @@ class _SearchContext:
         need_off = self.ev.all_dp_memory(global_batch, False) - limit
         items = (_materialize_items(self.items, bpd)
                  if self._has_slopes else self.items)
-        # per-slice remat savings can be far below the legacy 16 MiB
-        # knapsack quantum (one slice of one layer's activations), and
-        # each item loses up to one quantum to round-down — so the
-        # 4-mode knapsack sizes its grid from the coverage headroom:
-        # n/2 expected quanta of loss must fit inside it, else a
-        # coverable need quantizes to "uncoverable"
-        quantum = None
-        if need_off > 0 and items:
-            headroom = (sum(max(it.savings.values()) for it in items)
-                        - need_off)
-            quantum = min(16 * 2.0**20,
-                          max(2.0**16, headroom / len(items),
-                              need_off / 65536))
         best = self._solve_once(
             global_batch, items, self.item_slice, base_off, need_off,
-            lambda r: REMAT_ON if r else REMAT_OFF, osdp.search, 10_000,
-            quantum)
+            lambda r: REMAT_ON if r else REMAT_OFF, osdp.search, 10_000)
         nodes = best.nodes_visited
 
         mirrors = [(False, base_off, need_off)]
@@ -1578,7 +1469,7 @@ def search_hybrid(desc: ModelDescription, device: DeviceInfo,
     Sweeps every (dp, tp, pp) factorization of `n_devices` (or the
     given `candidates`); inside each, the DP dimension of the
     1/(tp*pp) model residue is decided by the existing Scheduler —
-    i.e. the dfs/knapsack/greedy solvers, or a forced uniform mode
+    i.e. the dfs search (or the ilp oracle), or a forced uniform mode
     when `osdp.force_mode` is set (force_mode="ZDP" reproduces plain
     DeepSpeed-style 3D; no force is 3D+OSDP).  Returns the global
     throughput argmax as a `HybridPlan`.

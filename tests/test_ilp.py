@@ -18,7 +18,7 @@ from repro.core.cost_model import CostEnv
 from repro.core.descriptions import describe
 from repro.core.ilp import HAVE_SCIPY_MILP, ILP_BACKENDS, solve_ilp
 from repro.core.search import (SliceItem, _solve_dfs, _solve_greedy,
-                               _solve_knapsack, search_plan)
+                               search_plan)
 
 MODES = ("ZDP", "ZDP+R", "DP+R")
 BACKENDS = [
@@ -142,17 +142,14 @@ def test_trivial_and_uncoverable(backend):
     triv = solve_ilp(items, 0.0, backend=backend)
     assert triv.optimal and triv.objective == 0.0
     assert triv.choice == [None] * 5
-    # uncoverable: proven infeasible, max-saving fallback (identical
-    # to _solve_knapsack's fallback; repair escalates all solvers to
-    # the same all-max plan)
+    # uncoverable: proven infeasible, max-saving fallback (repair
+    # escalates dfs's uncovered answer to the same all-max plan)
     need = 1.5 * _capacity(items)
     res = solve_ilp(items, need, backend=backend)
     assert res.optimal and math.isinf(res.objective)
     assert math.isinf(res.gap)
     expect = [max(it.savings, key=it.savings.get) for it in items]
     assert list(res.choice) == expect
-    kn, _ = _solve_knapsack(items, need)
-    assert list(kn) == expect
     _, t_greedy = _solve_greedy(items, need)
     assert math.isinf(t_greedy)
 
@@ -232,22 +229,15 @@ def test_nodes_visited_monotone_in_budget():
                            node_budget=b).nodes
                  for b in (3, 2_000_000)]
     assert ilp_nodes == sorted(ilp_nodes) and ilp_nodes[0] >= 1
-    # knapsack cells grow with the need (the DP cap is ceil(need/Q);
-    # unit-scale synthetic savings need an explicit sub-unit quantum)
-    q = _capacity(items) / 4096
-    _, c_lo = _solve_knapsack(items, 0.3 * _capacity(items), quantum=q)
-    _, c_hi = _solve_knapsack(items, 0.6 * _capacity(items), quantum=q)
-    assert 0 < c_lo <= c_hi
 
 
 def test_nodes_visited_short_circuit_zeros():
-    """0 is a legitimate effort value: dfs's root capacity prune and
-    knapsack's quantized-uncoverable check both bail before exploring.
-    The ilp still reports its model size (>= 1) on the same instance."""
+    """0 is a legitimate effort value: dfs's root capacity prune bails
+    before exploring.  The ilp still reports its model size (>= 1) on
+    the same instance."""
     items = _mk_multi(random.Random(7), 8)
     need = 1.5 * _capacity(items)
     assert _solve_dfs(items, need)[1] == 0
-    assert _solve_knapsack(items, need)[1] == 0
     assert solve_ilp(items, need, backend="bnb").nodes >= 1
     assert solve_ilp(items, 0.0).nodes >= 1
 
@@ -287,9 +277,7 @@ def test_search_plan_ilp_matches_dfs_byte_identical():
 
 
 def test_search_plan_nodes_visited_populated_per_solver():
-    """At 2.45 GiB every backend does real cover work (at 2.3 GiB the
-    knapsack's round-down quantization legitimately short-circuits to
-    its fallback with 0 cells — see the SearchResult comment)."""
+    """At 2.45 GiB dfs and the ilp both do real cover work."""
     for solver in SOLVERS:
         res = _qwen_search(solver, lim=int(2.45 * 2**30))
         assert res.feasible, solver
@@ -308,19 +296,18 @@ def test_osdp_api_exposes_certificate():
     assert plan.search.solver_backend in ("milp", "bnb")
 
 
-# --- the PR-3 regression pin: greedy (and truncated dfs) lose dominance -----
+# --- the PR-3 regression pin: the truncated dfs loses dominance ---------------
 
-def test_selective_remat_ilp_dominates_truncated_dfs_and_greedy():
+def test_selective_remat_ilp_dominates_truncated_dfs():
     """The case the audit was built for (PR 3 selective checkpointing):
     on the 4-mode phi4 per-layer problem at 16 GiB the dfs runs with a
     node cap (the unbudgeted search does not terminate in minutes on a
     problem the ILP closes in milliseconds), so its plan carries a real
     gap — measured 0.069% with the head tied to the embedding (2.27%
-    with the untied head) — and greedy's heuristic gap is 20.1% (8.79%)
-    and knapsack's 11.1%.
-    Pin both: the ILP must strictly dominate, and the measured gaps
-    must stay in their bands (a collapse to 0 means the budget cap
-    silently moved; a blow-up means a solver regressed)."""
+    with the untied head).
+    Pin it: the ILP must strictly dominate, and the measured gap must
+    stay in its band (a collapse to 0 means the budget cap silently
+    moved; a blow-up means the search regressed)."""
     from benchmarks.paper_models import MESH_8GPU, RTX_TITAN_8
     desc = describe(get_arch("phi4-mini-3.8b"), get_shape("train_4k"),
                     per_layer=True)
@@ -335,27 +322,23 @@ def test_selective_remat_ilp_dominates_truncated_dfs_and_greedy():
     t_ilp = res["ilp"].cost.time
     assert res["ilp"].proven_optimal is True
     gap = {s: res[s].cost.time / t_ilp - 1.0 for s in SOLVERS}
-    # ILP strictly dominates the truncated dfs and the greedy heuristic
+    # ILP strictly dominates the truncated dfs
     assert 2e-4 < gap["dfs"] < 2e-3, gap
-    assert 0.15 < gap["greedy"] < 0.25, gap
-    assert 0.08 < gap["knapsack"] < 0.14, gap
-    assert gap["greedy"] > gap["dfs"]
 
 
 # --- config surface ---------------------------------------------------------
 
 def test_solver_alias_and_validation():
-    assert OSDPConfig(solver="ilp").search == "ilp"
-    assert OSDPConfig(solver="greedy").search == "greedy"
-    # alias agrees with an explicit search=
-    assert OSDPConfig(solver="ilp", search="ilp").search == "ilp"
-    with pytest.raises(ValueError, match="solver"):
-        OSDPConfig(solver="ilp", search="greedy")
-    with pytest.raises(ValueError, match="search"):
-        OSDPConfig(search="simplex")
+    # `search` is the one name; there is no `solver=` alias
+    assert OSDPConfig(search="ilp").search == "ilp"
+    with pytest.raises(TypeError, match="solver"):
+        OSDPConfig(solver="ilp")
+    for name in ("simplex", "greedy"):
+        with pytest.raises(ValueError, match="search"):
+            OSDPConfig(search=name)
     with pytest.raises(ValueError, match="ilp_backend"):
         OSDPConfig(ilp_backend="cplex")
     with pytest.raises(ValueError, match="ilp_time_budget_s"):
         OSDPConfig(ilp_time_budget_s=-1.0)
     assert set(ILP_BACKENDS) == {"auto", "milp", "bnb"}
-    assert SOLVERS == ("dfs", "knapsack", "greedy", "ilp")
+    assert SOLVERS == ("dfs", "ilp")

@@ -1,8 +1,9 @@
 """PlanEvaluator equivalence: the vectorized/incremental fast path must
 return the same PlanCost (1e-9 rel) and the same argmin decisions as
 the direct `plan_cost` path — for arbitrary mixed plans, for O(1) flip
-sequences, and end-to-end through all three solvers (the pre-PR2
-search_plan semantics are replicated here as the golden reference)."""
+sequences, and end-to-end through dfs and its ilp oracle (the
+pre-optimization search_plan semantics are replicated here as the
+golden reference)."""
 import random
 
 import numpy as np
@@ -10,13 +11,13 @@ import pytest
 
 from repro.configs import (DeviceInfo, MULTI_POD_MESH, SINGLE_POD_MESH,
                            OSDPConfig, get_arch, get_shape)
-from repro.core.cost_model import (DP, MODES, ZDP, ZDP_POD, CostEnv,
+from repro.core.cost_model import (DP, MODES, ZDP, CostEnv,
                                    Decision, PlanEvaluator, plan_cost,
                                    uniform_plan)
 from repro.core.descriptions import describe
+from repro.core.ilp import solve_ilp
 from repro.core.search import (_build_items, _items_to_decisions,
-                               _solve_dfs, _solve_greedy, _solve_knapsack,
-                               search_plan)
+                               _solve_dfs, search_plan)
 
 MODELS = ("phi4-mini-3.8b", "dbrx-132b", "mamba2-2.7b")
 ENVS = {
@@ -122,10 +123,8 @@ def _reference_search_plan(desc, global_batch, env, osdp):
     need = base.memory - osdp.memory_limit_bytes
     if osdp.search == "dfs":
         choice, _ = _solve_dfs(items, need)
-    elif osdp.search == "knapsack":
-        choice, _ = _solve_knapsack(items, need)
     else:
-        choice, _ = _solve_greedy(items, need)
+        choice = solve_ilp(items, need).choice
     choice = list(choice)
     decisions = _items_to_decisions(desc, items, choice)
     cost = plan_cost(desc, decisions, global_batch, env)
@@ -160,7 +159,7 @@ CASES = [
 ]
 
 
-@pytest.mark.parametrize("solver", ("dfs", "knapsack", "greedy"))
+@pytest.mark.parametrize("solver", ("dfs", "ilp"))
 @pytest.mark.parametrize("model,limit_gib", CASES)
 def test_solvers_match_reference_path(solver, model, limit_gib):
     desc = describe(get_arch(model), get_shape("train_4k"))
@@ -176,10 +175,10 @@ def test_solvers_match_reference_path(solver, model, limit_gib):
     assert got.feasible == (want_cost.memory <= osdp.memory_limit_bytes)
 
 
-@pytest.mark.parametrize("solver", ("dfs", "knapsack", "greedy"))
+@pytest.mark.parametrize("solver", ("dfs", "ilp"))
 def test_solvers_match_reference_multi_pod(solver):
     """ZDP_POD adds a second mode per item — the grouped DFS and the
-    vectorized knapsack must still mirror the reference exactly."""
+    ILP oracle must still mirror the reference exactly."""
     desc = describe(get_arch("dbrx-132b"), get_shape("train_4k"))
     env = ENVS["multi_pod"]
     osdp = OSDPConfig(search=solver, memory_limit_bytes=24 * 2**30,
@@ -189,68 +188,6 @@ def test_solvers_match_reference_multi_pod(solver):
     got = search_plan(desc, 256, env, osdp)
     assert got.decisions == want_dec
     _assert_cost_equal(got.cost, want_cost, f"multi_pod/{solver}")
-
-
-def test_knapsack_matches_scalar_reference():
-    """Vectorized DP == the scalar list-of-lists DP, choice-for-choice."""
-    from repro.core.search import SliceItem
-
-    def scalar_knapsack(items, need, quantum):
-        n = len(items)
-        if need <= 0:
-            return [None] * n
-        cap = int(-(-need // quantum))
-        INF = float("inf")
-        dp = [INF] * (cap + 1)
-        dp[0] = 0.0
-        parent = [[None] * (cap + 1) for _ in range(n + 1)]
-        for i, it in enumerate(items):
-            ndp = dp[:]
-            npar = [None] * (cap + 1)
-            for m, sav in it.savings.items():
-                q = int(sav // quantum)
-                if q == 0:
-                    continue
-                t = it.extra_time[m]
-                for s in range(cap + 1):
-                    if dp[s] == INF:
-                        continue
-                    s2 = min(cap, s + q)
-                    if dp[s] + t < ndp[s2]:
-                        ndp[s2] = dp[s] + t
-                        npar[s2] = (s, m)
-            dp = ndp
-            parent[i + 1] = npar
-        if dp[cap] == INF:
-            return [max(it.savings, key=it.savings.get) for it in items]
-        choice = [None] * n
-        s = cap
-        for i in range(n, 0, -1):
-            p = parent[i][s]
-            if p is not None:
-                s, m = p
-                choice[i - 1] = m
-        return choice
-
-    rng = random.Random(3)
-    for trial in range(20):
-        n = rng.randrange(3, 30)
-        two_modes = rng.random() < 0.5
-        items = []
-        for i in range(n):
-            sav = {ZDP: rng.uniform(0, 100)}
-            ext = {ZDP: rng.uniform(0.0, 10.0)}
-            if two_modes:
-                sav[ZDP_POD] = rng.uniform(0, 100)
-                ext[ZDP_POD] = rng.uniform(0.0, 10.0)
-            items.append(SliceItem(f"op{i}", 0, 1, sav, ext))
-        total = sum(max(it.savings.values()) for it in items)
-        need = rng.uniform(0.1, 1.2) * total
-        quantum = total / rng.choice([64, 256, 1024])
-        want = scalar_knapsack(items, need, quantum)
-        got, cells = _solve_knapsack(items, need, quantum)
-        assert got == want, (trial, need, quantum)
-        assert cells >= 0
 
 
 def test_grouped_dfs_exact_with_duplicate_items():
@@ -381,7 +318,7 @@ def test_forced_uniform_explicit_remat_matches_legacy_flag(model, flag):
             _assert_cost_equal(got, want, f"{model}/{flag}/b{batch}")
 
 
-@pytest.mark.parametrize("solver", ("dfs", "knapsack", "greedy"))
+@pytest.mark.parametrize("solver", ("dfs", "ilp"))
 def test_legacy_bool_configs_decisions_unchanged(solver):
     """checkpointing=True/False searches must return remat-free
     decisions (remat inherited from the env flag), exactly as pre-PR."""
@@ -395,11 +332,11 @@ def test_legacy_bool_configs_decisions_unchanged(solver):
 
 
 def test_solver_effort_is_reported():
-    """nodes_visited: dfs = nodes expanded, knapsack = cells relaxed,
-    greedy = items ranked — all populated for the bench JSON."""
+    """nodes_visited: dfs = nodes expanded, ilp = integer variables +
+    branch-and-bound nodes — both populated for the bench JSON."""
     desc = describe(get_arch("phi4-mini-3.8b"), get_shape("train_4k"))
     env = ENVS["single_pod"]
-    for solver in ("dfs", "knapsack", "greedy"):
+    for solver in ("dfs", "ilp"):
         # 4 GiB: below the all-DP footprint, so every solver must work
         res = search_plan(desc, 256, env, OSDPConfig(
             search=solver, memory_limit_bytes=4 * 2**30))

@@ -1,20 +1,30 @@
-"""Search engine (Algorithm 1) correctness: DFS == brute force == knapsack
-on small instances; pruned DFS scales; Scheduler picks the throughput
-argmax."""
+"""Search engine (Algorithm 1) correctness: DFS == brute force on small
+instances and == the ILP oracle past brute force's reach; pruned DFS
+scales; Scheduler picks the throughput argmax; the benchmark cells'
+plans are pinned."""
+import importlib
 import itertools
+import json
 import math
+import pathlib
 import random
+import sys
 
 import hypothesis.strategies as st
 import pytest
 from hypothesis import given, settings
 
-from repro.configs import (DeviceInfo, SINGLE_POD_MESH, OSDPConfig,
-                           get_arch, get_shape)
+from repro.configs import (DeviceInfo, MeshConfig, ModelConfig,
+                           OSDPConfig, RunConfig, SINGLE_POD_MESH,
+                           ShapeConfig, get_arch, get_shape)
 from repro.core.cost_model import CostEnv, DP, ZDP
 from repro.core.descriptions import describe
+from repro.core.ilp import solve_ilp
+from repro.core.plan import make_plan
 from repro.core.search import (SliceItem, _solve_dfs, _solve_greedy,
-                               _solve_knapsack, schedule, search_plan)
+                               schedule, search_plan)
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 
 def _mk_items(rng, n):
@@ -53,18 +63,50 @@ def test_dfs_matches_brute_force(seed):
     assert t_dfs == pytest.approx(t_bf, rel=1e-9)
 
 
-@pytest.mark.parametrize("seed", range(4))
-def test_knapsack_near_optimal(seed):
-    rng = random.Random(100 + seed)
-    items = _mk_items(rng, 12)
-    total = sum(it.savings[ZDP] for it in items)
-    need = 0.5 * total
-    t_bf = _brute_force(items, need)
-    choice, _ = _solve_knapsack(items, need, quantum=total / 4096)
+def _mk_remat_items(rng, n):
+    """Sharding x remat items as the selective search builds them: ZDP
+    alone, remat alone (DP+R), and both (ZDP+R, which also pays the
+    remat'd 4th gather), drawn from a few per-layer signatures so the
+    dfs's group collapsing has groups to collapse."""
+    sigs = []
+    for _ in range(max(2, n // 8)):
+        s_z, t_z = rng.uniform(1, 100), rng.uniform(0.01, 10.0)
+        s_r, t_r = rng.uniform(1, 100), rng.uniform(0.01, 10.0)
+        sigs.append(({ZDP: s_z, "DP+R": s_r, "ZDP+R": s_z + s_r},
+                     {ZDP: t_z, "DP+R": t_r,
+                      "ZDP+R": t_z + t_r + rng.uniform(0.0, t_z / 3)}))
+    return [SliceItem(f"op{i}", 0, 1, *rng.choice(sigs)) for i in range(n)]
+
+
+def _cover(items, choice):
     sav = sum(items[i].savings[c] for i, c in enumerate(choice) if c)
     t = sum(items[i].extra_time[c] for i, c in enumerate(choice) if c)
-    assert sav >= need * (1 - 2e-3)
-    assert t <= t_bf * 1.05 + 1e-9
+    return sav, t
+
+
+@pytest.mark.parametrize("kind", ("2mode", "4mode"))
+@pytest.mark.parametrize("seed", range(8))
+def test_dfs_matches_ilp_beyond_brute_force(seed, kind):
+    """Brute force stops at 10 items; the ILP oracle reaches 30-60. A
+    dfs that finishes under its node budget is exact; one cut off is
+    never below the optimum and still covers the need."""
+    rng = random.Random(300 + seed)
+    n = rng.randrange(30, 61)
+    items = (_mk_items(rng, n) if kind == "2mode"
+             else _mk_remat_items(rng, n))
+    need = rng.uniform(0.2, 0.8) * sum(max(it.savings.values())
+                                       for it in items)
+    budget = 20_000
+    choice, nodes = _solve_dfs(items, need, budget)
+    ref = solve_ilp(items, need)
+    assert ref.optimal
+    sav, t = _cover(items, choice)
+    ref_sav, ref_t = _cover(items, ref.choice)
+    assert sav >= need - 1e-9 and ref_sav >= need - 1e-9
+    if nodes <= budget:
+        assert t == pytest.approx(ref_t, rel=1e-9)
+    else:
+        assert t >= ref_t * (1 - 1e-9)
 
 
 @pytest.mark.parametrize("seed", range(4))
@@ -143,3 +185,69 @@ def test_osdp_between_dp_and_fsdp():
     assert p.cost.memory <= 16 * 2**30 * 1.001
     assert p.cost.time <= pf.cost.time * 1.001
     assert p.cost.memory <= pd.cost.memory * 1.001
+
+
+# --- the benchmark cells' own planning problems -----------------------------
+
+# each operator's modes per slice in the plan the benchmark cells have
+# compiled and measured on the chip; a change here changes what they run
+_ALL_DP = {"embed.tok": ("DP",), "final_norm": ("DP",),
+           "layers.attn_qkv": ("DP",) * 4, "layers.attn_out": ("DP",) * 4,
+           "layers.attn_scores": ("DP",), "layers.attn_norm": ("DP",),
+           "layers.ffn_w13": ("DP",) * 4, "layers.ffn_w2": ("DP",) * 4,
+           "layers.ffn_norm": ("DP",)}
+CELL_PLANS = {
+    "qwen1.5-0.5b": _ALL_DP,
+    "phi-4-mini": dict(_ALL_DP, **{
+        "layers.attn_qkv": ("ZDP", "ZDP", "DP", "DP"),
+        "layers.attn_out": ("ZDP", "DP", "DP", "DP"),
+        "layers.ffn_w13": ("ZDP",) * 4,
+        "layers.ffn_w2": ("ZDP",) * 4}),
+}
+
+
+def _cell_plan(config: str, search: str):
+    """The plan `chipbench` makes for a train cell: its model from the
+    configuration file, its traffic's batch and sequence, a (chips, 1)
+    data mesh, the deployment's memory limit, priced for the v5e."""
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    cfg_entry, = (c for c in bench["configs"] if c["name"] == config)
+    cell, = (w for w in bench["workloads"] if w["config"] == config)
+    cfg = json.loads((ROOT / cfg_entry["file"]).read_text())
+    mix = json.loads((ROOT / "chipbench" / "traffic"
+                      / f"{cell['traffic']}.json").read_text())
+    sys.path.insert(0, str(ROOT))
+    try:
+        family = importlib.import_module(
+            f"chipbench.families.{cfg['family']}")
+    finally:
+        sys.path.pop(0)
+    model = ModelConfig(name=cfg["name"], **family.program_kwargs(cfg))
+    run = RunConfig(
+        model=model,
+        shape=ShapeConfig("chipbench", mix["seq"], mix["batch"], "train"),
+        mesh=MeshConfig((cell["chips"], 1), ("data", "model")),
+        osdp=OSDPConfig(memory_limit_bytes=cfg["deployment"]
+                        ["memory_limit_gib"] * 2**30, search=search))
+    return make_plan(run, DeviceInfo.preset("tpu-v5e"))
+
+
+@pytest.mark.parametrize("config", sorted(CELL_PLANS))
+def test_cell_plan_pinned(config):
+    """The chip cells plan with the default search, dfs; their plans stay
+    decision for decision what the chip has compiled and measured."""
+    plan = _cell_plan(config, "dfs")
+    assert plan.search.solver == "dfs" and plan.search.feasible
+    got = {name: d.modes for name, d in plan.decisions.items()}
+    assert got == CELL_PLANS[config]
+    assert all(d.remat is None for d in plan.decisions.values())
+
+
+@pytest.mark.parametrize("config", sorted(CELL_PLANS))
+def test_cell_plan_is_optimal(config):
+    """The ILP oracle, on the cell's own problem, reaches the dfs plan's
+    step cost: the cell runs the optimum of the cost model."""
+    dfs = _cell_plan(config, "dfs")
+    ilp = _cell_plan(config, "ilp")
+    assert ilp.search.proven_optimal and ilp.search.feasible
+    assert dfs.cost.time == pytest.approx(ilp.cost.time, rel=1e-12)

@@ -37,7 +37,7 @@ def _thr(res):
 
 # --- dominance: selective >= max(global on, global off) ---------------------
 
-@pytest.mark.parametrize("solver", ("dfs", "knapsack"))
+@pytest.mark.parametrize("solver", ("dfs", "ilp"))
 @pytest.mark.parametrize("model,lim_gib", [
     ("phi4-mini-3.8b", 3), ("phi4-mini-3.8b", 6), ("phi4-mini-3.8b", 12),
     ("mamba2-2.7b", 4), ("mamba2-2.7b", 10),

@@ -1,22 +1,20 @@
-"""Differential oracle for all four cover solvers (hypothesis).
+"""Differential oracle for the dfs search, its greedy incumbent and the
+ILP (hypothesis).
 
 Property-based companion to tests/test_ilp.py: random multi-mode
 instances small enough to enumerate exhaustively, and the theorem each
-solver is supposed to satisfy:
+is supposed to satisfy:
 
   * ilp (both backends) == brute force == dfs, to 1e-9;
-  * knapsack is exact *on its quantized problem* — its true-cost gap
-    is purely quantization loss, which benchmarks/solver_audit.py
-    bounds on the real model zoo;
-  * greedy never beats the optimum, and on single-mode instances its
-    overshoot is bounded by its final pick (the ratio-prefix theorem:
-    the prefix minus the last item is the cheapest fractional cover of
-    its own coverage, which undershoots the need — so greedy <= OPT +
-    ext of the last item taken);
-  * uncoverable instances are detected by every backend, with the
-    byte-identical fallback on single-mode instances (multi-mode
-    fallbacks differ per solver; search_plan's repair escalates all of
-    them to the same all-max plan — asserted by the audit's
+  * the ratio greedy (dfs's incumbent) never beats the optimum, and on
+    single-mode instances its overshoot is bounded by its final pick
+    (the ratio-prefix theorem: the prefix minus the last item is the
+    cheapest fractional cover of its own coverage, which undershoots
+    the need — so greedy <= OPT + ext of the last item taken);
+  * uncoverable instances are detected by dfs, its incumbent and the
+    ilp, with the byte-identical fallback on single-mode instances
+    (multi-mode fallbacks differ; search_plan's repair escalates all
+    of them to the same all-max plan — asserted by the audit's
     decisions_identical column on the committed infeasible rows).
 """
 import itertools
@@ -27,8 +25,7 @@ import pytest
 from hypothesis import example, given, settings
 
 from repro.core.ilp import HAVE_SCIPY_MILP, solve_ilp
-from repro.core.search import (SliceItem, _solve_dfs, _solve_greedy,
-                               _solve_knapsack)
+from repro.core.search import SliceItem, _solve_dfs, _solve_greedy
 
 MODES = ("ZDP", "ZDP+R", "DP+R")
 
@@ -133,30 +130,6 @@ def test_dfs_matches_ilp_cost(inst):
         assert _close(_cost(items, choice), res.objective)
 
 
-@settings(max_examples=60, deadline=None)
-@given(instances(max_frac=0.95), st.integers(16, 256))
-def test_knapsack_exact_on_quantized_problem(inst, buckets):
-    """Round savings down to the quantum, round the need up: knapsack
-    must hit the exact optimum of THAT problem (cost-wise); the
-    true-problem gap is bounded by what quantization destroyed."""
-    items, need = inst
-    q = sum(max(it.savings.values()) for it in items) / buckets
-    choice, _ = _solve_knapsack(items, need, quantum=q)
-    q_items = [SliceItem(it.op_name, 0, 1,
-                         {m: (it.savings[m] // q) * q
-                          for m in it.savings},
-                         dict(it.extra_time)) for it in items]
-    q_need = math.ceil(need / q) * q
-    ref = _brute(q_items, q_need - 1e-9 * q)
-    if math.isinf(ref):
-        # quantized-uncoverable: documented max-saving fallback
-        assert list(choice) == [max(it.savings, key=it.savings.get)
-                                for it in items]
-    else:
-        assert _cover(q_items, choice) >= q_need - 1e-6 * q
-        assert _close(_cost(items, choice), ref)
-
-
 @settings(max_examples=80, deadline=None)
 @given(instances(max_modes=1))
 def test_greedy_bounded_by_prefix_theorem(inst):
@@ -176,12 +149,11 @@ def test_greedy_bounded_by_prefix_theorem(inst):
 @settings(max_examples=60, deadline=None)
 @given(instances(max_modes=1, min_frac=1.01, max_frac=1.6))
 def test_uncoverable_single_mode_identical_fallback(inst):
-    """Single-mode uncoverable: all four land on the same all-shard
-    fallback, byte for byte."""
+    """Single-mode uncoverable: dfs, its greedy incumbent and the ilp
+    land on the same all-shard fallback, byte for byte."""
     items, need = inst
     expect = [max(it.savings, key=it.savings.get) for it in items]
     assert list(_solve_dfs(items, need)[0]) == expect
-    assert list(_solve_knapsack(items, need)[0]) == expect
     g_choice, g_t = _solve_greedy(items, need)
     assert list(g_choice) == expect and math.isinf(g_t)
     res = solve_ilp(items, need, backend="bnb")
@@ -196,9 +168,7 @@ def test_uncoverable_multi_mode_detected_by_all(inst):
     short of the need / inf objective) — the identical final plan is
     restored by search_plan's all-max escalation."""
     items, need = inst
-    for choice in (_solve_dfs(items, need)[0],
-                   _solve_knapsack(items, need)[0]):
-        assert _cover(items, choice) < need
+    assert _cover(items, _solve_dfs(items, need)[0]) < need
     assert math.isinf(_solve_greedy(items, need)[1])
     res = solve_ilp(items, need, backend="bnb")
     assert res.optimal and math.isinf(res.objective)
